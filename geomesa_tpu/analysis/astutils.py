@@ -5,9 +5,8 @@ linted code. The two load-bearing pieces:
 
 - :class:`ImportMap` — canonicalizes local names to dotted import paths
   (``jnp.where`` → ``jax.numpy.where``) so rules match semantics, not
-  spelling. ``import jax.numpy as jnp``, ``from jax import jit``, and the
-  repo's own compat shim (``from geomesa_tpu.utils.jax_compat import
-  shard_map``) all resolve to the same canonical names.
+  spelling. ``import jax.numpy as jnp`` and ``from jax import jit``
+  resolve to the same canonical names.
 - taint propagation — a per-function forward pass marking names that
   (transitively) hold traced/device values, with shape/dtype-style
   accesses shielded because they are static under tracing.
@@ -40,12 +39,6 @@ STATIC_ATTRS = frozenset({
 STATIC_CALLS = frozenset({"len", "isinstance", "type", "hasattr"})
 
 
-# The repo's version-bridging re-exports: symbols imported from here ARE
-# the jax API and must canonicalize as such, or taint/jit detection loses
-# every module that routes through the shim.
-_COMPAT_MODULE = "geomesa_tpu.utils.jax_compat"
-
-
 class ImportMap:
     """Local name → canonical dotted path, from a module's import statements."""
 
@@ -63,10 +56,7 @@ class ImportMap:
             elif isinstance(node, ast.ImportFrom) and node.module:
                 for alias in node.names:
                     local = alias.asname or alias.name
-                    module = node.module
-                    if module == _COMPAT_MODULE:
-                        module = "jax"  # shard_map/enable_x64 re-exports
-                    self.names[local] = f"{module}.{alias.name}"
+                    self.names[local] = f"{node.module}.{alias.name}"
 
     def resolve(self, node: ast.AST) -> str | None:
         """Canonical dotted path of a Name/Attribute chain, or None."""

@@ -71,7 +71,7 @@ __all__ = [
     "choreography_boundary", "MUTATION_KINDS", "DEATH_KINDS",
 ]
 
-# The mutation taxonomy F001 reasons over. ``DEATH_KINDS`` are the
+# The mutation classification F001 reasons over. ``DEATH_KINDS`` are the
 # name-death transitions: a type NAME stops answering for its old data,
 # so everything keyed by the name must be purged (a recreated same-name
 # successor restarts the (rebuild epoch, delta version) tuple at equal

@@ -255,7 +255,7 @@ def make_block_bbox_count_step(mesh, block: int):
     as distributed select (SURVEY.md §7)."""
     from functools import partial
 
-    from geomesa_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from geomesa_tpu.parallel.mesh import DATA_AXIS
@@ -306,7 +306,7 @@ def make_block_bbox_gather_step(mesh, block: int, capacity: int):
     on shard d matching polygon p (unused lanes hold -1)."""
     from functools import partial
 
-    from geomesa_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from geomesa_tpu.parallel.mesh import DATA_AXIS
@@ -364,7 +364,7 @@ def make_block_join_step(mesh, block: int):
     """
     from functools import partial
 
-    from geomesa_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from geomesa_tpu.parallel.mesh import DATA_AXIS

@@ -29,11 +29,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import enable_x64
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from geomesa_tpu.ops.refine import MAX_BOXES, MAX_TIMES
-from geomesa_tpu.utils.jax_compat import enable_x64
 
 LANES = 128
 

@@ -24,7 +24,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from geomesa_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from geomesa_tpu.parallel.mesh import DATA_AXIS, Mesh, data_shards

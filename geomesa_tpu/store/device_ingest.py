@@ -116,14 +116,14 @@ def device_sort_perm(
     if n and int(route_key.max()) == 2**64 - 1:
         raise ValueError("device_sort_perm: route key collides with sentinel")
     shards = data_shards(mesh)
-    rowid = np.arange(n, dtype=np.int32)
-    payload = {"rowid": rowid}
-    lex = 0
-    if tiebreak is not None:
-        payload = {"tie": tiebreak.astype(np.int32), "rowid": rowid}
-        lex = 1
+    # no tiebreak sorts by an all-zero one: every build then shares ONE
+    # compiled reshard program per shape (its sorts take minutes to
+    # compile for the TPU; the zero column costs one int32 pass)
+    tie = (np.zeros(n, np.int32) if tiebreak is None
+           else tiebreak.astype(np.int32))
+    payload = {"tie": tie, "rowid": np.arange(n, dtype=np.int32)}
     _, cols_out, counts, _splits = _reshard_with_retry(
-        mesh, route_key, payload, lex_cols=lex
+        mesh, route_key, payload, lex_cols=1
     )
     # per-shard sorted rowids, concatenated in shard order = global sort.
     # cols_out["rowid"] is (S * S*capacity) device-sharded; shard d's first

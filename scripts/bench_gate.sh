@@ -10,16 +10,13 @@
 #   3. red       — the same comparison with a synthetically injected 20%
 #                  slowdown (GEOMESA_BENCH_INJECT_SLOWDOWN=1.2) must exit
 #                  non-zero at the default 15% threshold.
-#   4. committed — the committed BENCH_DETAIL.json (the last real-chip
-#                  sweep) must load as a baseline and pass against its own
-#                  values: `--regress BENCH_DETAIL.json` exits 0.
 #
-# Legs 2-4 reuse recorded measurements (GEOMESA_BENCH_REGRESS_MEASURED)
+# Legs 2-3 reuse recorded measurements (GEOMESA_BENCH_REGRESS_MEASURED)
 # instead of re-measuring, so the red/green contract is DETERMINISTIC: CI
 # containers on shared hosts show >2x wall-clock jitter between identical
 # runs, and a 15% absolute-time gate on fresh measurements there flakes by
 # construction. Real rounds on real hardware run the full re-measuring
-# gate instead:  python bench.py --regress BENCH_DETAIL.json
+# gate instead, against a --regress-capture file taken on the chip
 # (see docs/operations.md § Benchmarks).
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -53,15 +50,15 @@ export GEOMESA_BENCH_REGRESS_CONFIGS="${GEOMESA_BENCH_REGRESS_CONFIGS:-2,6,8,9,1
 tmp="$(mktemp -d)"
 trap 'rm -rf "$tmp"' EXIT
 
-echo "[bench-gate] 1/10 capture (real measurement, K=$GEOMESA_BENCH_REGRESS_K)"
+echo "[bench-gate] 1/9 capture (real measurement, K=$GEOMESA_BENCH_REGRESS_K)"
 python bench.py --regress-capture "$tmp/baseline.json"
 
-echo "[bench-gate] 2/10 green: regress vs capture must pass"
+echo "[bench-gate] 2/9 green: regress vs capture must pass"
 GEOMESA_BENCH_REGRESS_MEASURED="$tmp/baseline.json" \
     python bench.py --regress "$tmp/baseline.json" \
     --regress-report "$tmp/report.json"
 
-echo "[bench-gate] 3/10 red: injected 20% slowdown must FAIL the gate"
+echo "[bench-gate] 3/9 red: injected 20% slowdown must FAIL the gate"
 if GEOMESA_BENCH_INJECT_SLOWDOWN=1.2 \
     GEOMESA_BENCH_REGRESS_MEASURED="$tmp/baseline.json" \
     python bench.py --regress "$tmp/baseline.json" >/dev/null; then
@@ -69,17 +66,12 @@ if GEOMESA_BENCH_INJECT_SLOWDOWN=1.2 \
     exit 1
 fi
 
-echo "[bench-gate] 4/10 committed baseline loads and passes against itself"
-GEOMESA_BENCH_REGRESS_CONFIGS="" \
-    GEOMESA_BENCH_REGRESS_MEASURED=BENCH_DETAIL.json \
-    python bench.py --regress BENCH_DETAIL.json >/dev/null
-
 # capture → replay → parity smoke (ISSUE 11): a tiny two-tenant workload
 # captured with GEOMESA_TPU_WORKLOAD_DIR, replayed closed-loop, must
 # reproduce byte-identical per-query row counts, emit a per-signature
 # recorded-vs-replayed report loadable as a --regress baseline, and hold
 # the K+1 tenant label-cardinality bound on the prometheus exposition.
-echo "[bench-gate] 5/10 workload capture -> replay -> parity smoke"
+echo "[bench-gate] 4/9 workload capture -> replay -> parity smoke"
 python scripts/replay_smoke.py
 
 # serving-plane smoke (ISSUE 12): replay a tiny captured two-tenant
@@ -88,7 +80,7 @@ python scripts/replay_smoke.py
 # coalesce width > 1 (fewer device dispatches than queries), and shed
 # correctness (the over-budget tenant answers 429 + Retry-After while
 # the healthy tenant keeps answering 200). See docs/serving.md.
-echo "[bench-gate] 6/10 serving: admission + coalescing replay parity smoke"
+echo "[bench-gate] 5/9 serving: admission + coalescing replay parity smoke"
 python scripts/serving_smoke.py
 
 # correctness-auditor smoke (ISSUE 13): green leg — a clean mixed
@@ -98,7 +90,7 @@ python scripts/serving_smoke.py
 # injected one-row device corruption (FaultInjector kind=flip) must
 # produce >= 1 divergence with a repro bundle that replays to the same
 # divergence. The gate fails if the auditor stays silent.
-echo "[bench-gate] 7/10 correctness auditor: green + red (injected corruption)"
+echo "[bench-gate] 6/9 correctness auditor: green + red (injected corruption)"
 python scripts/audit_smoke.py
 
 # durability crash harness (ISSUE 14): green leg — N SIGKILL/recover
@@ -108,7 +100,7 @@ python scripts/audit_smoke.py
 # parity, and clean invariant sweeps; red leg — GEOMESA_TPU_WAL_UNSAFE
 # acks before durability with a crash injected in that window, and the
 # harness MUST detect the loss (the gate fails if it stays silent).
-echo "[bench-gate] 8/10 durability: kill-and-recover crash harness (green + red)"
+echo "[bench-gate] 7/9 durability: kill-and-recover crash harness (green + red)"
 python scripts/crash_smoke.py --cycles "${GEOMESA_CRASH_CYCLES:-8}" --rows 24
 python scripts/crash_smoke.py --red --cycles 3 --rows 24
 
@@ -119,7 +111,7 @@ python scripts/crash_smoke.py --red --cycles 3 --rows 24
 # export with 5x the measured dispatch rate must flag the declaration
 # (the gate fails if the divergence stays silent). The static half of
 # the fusion work list: docs/tpulint.md § Sync rules.
-echo "[bench-gate] 9/10 tpusync: static budgets vs measured ledger (green + red)"
+echo "[bench-gate] 8/9 tpusync: static budgets vs measured ledger (green + red)"
 python scripts/sync_reconcile_smoke.py
 
 # elastic-federation rebalance harness (ISSUE 19): green leg — live
@@ -127,7 +119,7 @@ python scripts/sync_reconcile_smoke.py
 # crash points, zero acked-write loss/duplication and clean coverage at
 # every generation; red leg — the dual-apply state is DISABLED, opening
 # a real loss window the referee must detect (silence fails the gate).
-echo "[bench-gate] 10/10 elastic: live-rebalance crash harness (green + red)"
+echo "[bench-gate] 9/9 elastic: live-rebalance crash harness (green + red)"
 python scripts/rebalance_smoke.py --cycles "${GEOMESA_REBALANCE_CYCLES:-8}"
 python scripts/rebalance_smoke.py --red --cycles 3
 

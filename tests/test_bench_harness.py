@@ -225,15 +225,6 @@ class TestRegressGate:
         base = mod._load_regress_baseline(str(rep))
         assert base["2"]["value"] == 5.5
 
-    def test_committed_detail_loads_as_baseline(self):
-        """The committed real-chip sweep record must stay loadable — the
-        production gate is `bench.py --regress BENCH_DETAIL.json`."""
-        mod = _bench_mod()
-        base = mod._load_regress_baseline("BENCH_DETAIL.json")
-        assert base, "BENCH_DETAIL.json yielded no baseline configs"
-        for cfg, b in base.items():
-            assert b["value"] is not None and b["unit"], cfg
-
 
 def test_driver_line_compact_and_parseable(tmp_path):
     """Driver-mode emission contract: the LAST stdout line parses as JSON,
@@ -270,3 +261,50 @@ def test_driver_line_compact_and_parseable(tmp_path):
     bad = dict(configs["2"])
     bad["detail"] = {"int_domain_parity": True, "row_set_parity": False}
     assert mod._compact(bad)["parity"] is False
+
+
+class TestBackendProbe:
+    """No TPU is a failure, never a silent CPU fallback: only an explicit
+    JAX_PLATFORMS=cpu makes a run a rehearsal, labelled "cpu"."""
+
+    @staticmethod
+    def _probe(monkeypatch, stdout, platforms=None, rc=0):
+        mod = _bench_mod("bench_probe_mod")
+        done = subprocess.CompletedProcess([], rc, stdout, "boom")
+        monkeypatch.setattr(mod, "_run_with_graceful_timeout",
+                            lambda *a, **k: done)
+        if platforms is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        return mod._probe_backend()
+
+    def test_tpu_passes(self, monkeypatch):
+        assert self._probe(monkeypatch, "tpu 1\n")[:2] == ("tpu", 1)
+
+    def test_cpu_rehearsal_labels_itself(self, monkeypatch):
+        assert self._probe(monkeypatch, "cpu 8\n", "cpu")[:2] == ("cpu", 8)
+
+    @pytest.mark.parametrize("stdout,platforms,rc", [
+        ("cpu 8\n", None, 0),       # no TPU found, CPU not asked for
+        ("cpu 8\n", "tpu,cpu", 0),  # asked for the TPU, got the CPU
+        ("", None, 1),              # backend failed to start
+    ])
+    def test_no_tpu_exits(self, monkeypatch, stdout, platforms, rc):
+        with pytest.raises(SystemExit):
+            self._probe(monkeypatch, stdout, platforms, rc)
+
+
+class TestHbmPeak:
+    class _Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    def test_v5e_from_published_table(self):
+        mod = _bench_mod()
+        assert mod.hbm_peak_gbps(self._Dev("TPU v5 lite")) == 819.0
+
+    def test_unknown_kind_is_an_error(self):
+        mod = _bench_mod()
+        with pytest.raises(KeyError, match="TPU v99"):
+            mod.hbm_peak_gbps(self._Dev("TPU v99"))

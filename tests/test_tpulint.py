@@ -67,15 +67,15 @@ class TestRuleFixtures:
 
 
 class TestImportCanonicalization:
-    def test_compat_shim_resolves_as_jax(self):
-        """Symbols re-exported by utils/jax_compat ARE the jax API — the
-        taint/jit machinery must see through the shim."""
+    def test_from_jax_import_resolves_as_jax(self):
+        """``from jax import shard_map`` and an aliased module import both
+        canonicalize to the jax API the taint/jit machinery matches."""
         import ast as _ast
 
         from geomesa_tpu.analysis.astutils import ImportMap
 
         tree = _ast.parse(
-            "from geomesa_tpu.utils.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "import jax.numpy as jnp\n")
         im = ImportMap(tree)
         assert im.names["shard_map"] == "jax.shard_map"

@@ -8,8 +8,8 @@ kernels unwitnessed" gap. Run explicitly:
     GEOMESA_TPU_DEVICE_TESTS=1 python -m pytest tests/tpu/ -q -p no:cacheprovider
 
 It self-skips unless ``GEOMESA_TPU_DEVICE_TESTS=1`` AND a non-CPU jax
-backend initializes; results are recorded by ``scripts`` runs into
-``TPU_VALIDATION.md`` at the repo root.
+backend initializes; per-test results of the chip run are recorded in
+``CHANGES.md``.
 """
 
 import os
@@ -546,12 +546,22 @@ class TestRound3DevicePaths:
                 jnp.asarray(boxes), jnp.asarray(times))
         seg = make_grouped_agg_step(mesh, G, 1, 256, impl="segment")(*args)
         mxu = make_grouped_agg_step(mesh, G, 1, 256, impl="mxu")(*args)
-        np.testing.assert_array_equal(np.asarray(seg[0]), np.asarray(mxu[0]))
-        np.testing.assert_array_equal(np.asarray(seg[2]), np.asarray(mxu[2]))
-        # numpy ground truth for the counts
+        # numpy ground truth per impl: the fold counts the interior, and
+        # rows ON a box edge (x == 0 / 800_000, y == 0 — about one per run
+        # at this n) come back as gathered candidates for the host's
+        # re-test, so truth is fold + candidates
         m = (x >= 0) & (x <= 800_000)
         want = np.bincount(gid[m], minlength=G)
-        np.testing.assert_array_equal(np.asarray(mxu[0])[0], want)
+        for name, r in (("segment", seg), ("mxu", mxu)):
+            pos, hits = np.asarray(r[6])[0], np.asarray(r[7])[0]
+            cand = np.concatenate(
+                [pos[d, : hits[d]] for d in range(pos.shape[0])]
+            ).astype(np.int64)
+            edge = np.bincount(gid[cand], minlength=G)
+            np.testing.assert_array_equal(
+                np.asarray(r[0])[0] + edge, want, err_msg=f"{name} bincount")
+        np.testing.assert_array_equal(np.asarray(seg[0]), np.asarray(mxu[0]))
+        np.testing.assert_array_equal(np.asarray(seg[2]), np.asarray(mxu[2]))
 
     def test_planned_count_pruned_scan_on_hardware(self, rng):
         """Round-5 surface (VERDICT r4 item 3): the index-pruned resident
