@@ -345,6 +345,21 @@ class _NullCtx:
 _DISPATCH_GATE = threading.RLock()
 
 
+def _name_module(fn, name: str):
+    """Make the jitted ``fn`` lower to the XLA module ``jit_<name>``.
+
+    jax names a module after the Python function it traces, and every
+    step factory calls that function ``step``, so without this each
+    program is ``jit_step`` in a device trace's ``XLA Modules`` line.
+    Renaming the traced function before its first trace changes the
+    module's name only: the HLO, and so the compiled program, are the
+    same. Anything but a jitted function is returned untouched."""
+    inner = getattr(fn, "__wrapped__", None)
+    if inner is not None and hasattr(fn, "lower"):
+        inner.__name__ = name
+    return fn
+
+
 def observed(name: str, fn):
     """Wrap one cached jit step with the signature-keyed sampler.
 
@@ -352,8 +367,12 @@ def observed(name: str, fn):
     step owns one wrapper and one signature set for the life of the
     cache. Metric handles are resolved ONCE here (names are fixed per
     wrapper) so the per-dispatch cost is increments, not name lookups.
+    The step lowers to the XLA module ``jit_<name>`` (:func:`_name_module`),
+    so its device time carries the same name as its census.
     """
     from geomesa_tpu.obs import trace as _trace
+
+    _name_module(fn, name)
 
     sigs: set = set()
     lock = threading.Lock()

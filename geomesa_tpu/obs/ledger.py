@@ -44,6 +44,7 @@ from contextlib import contextmanager
 from contextvars import ContextVar
 
 from geomesa_tpu.analysis.contracts import cache_surface, feedback_sink
+from geomesa_tpu.obs import trace as _trace
 
 __all__ = [
     "QueryLedger", "LedgerTable", "roundtrip", "current", "note_dispatch",
@@ -168,15 +169,18 @@ def note_dispatch(t0: float, t1: float, *, compiled: bool = False,
 def materialize(obj):
     """``np.asarray`` with sync-point accounting: the canonical way for a
     backend to pull a device result to host. Off path (no ledger open)
-    degrades to a bare ``np.asarray``."""
+    degrades to a bare ``np.asarray``. Runs inside a ``sync`` span, so a
+    traced query shows the wait for the device (and the copy back) as
+    its own stage."""
     import numpy as np
 
     ql = _led_var.get()
-    if ql is None:
-        return np.asarray(obj)
-    t0 = time.perf_counter()
-    out = np.asarray(obj)
-    ql.note_sync(t0, time.perf_counter())
+    with _trace.span("sync"):
+        if ql is None:
+            return np.asarray(obj)
+        t0 = time.perf_counter()
+        out = np.asarray(obj)
+        ql.note_sync(t0, time.perf_counter())
     return out
 
 

@@ -10,15 +10,24 @@ without any explicit plumbing.
 
 Zero-overhead contract: with tracing disabled, :func:`span` returns a
 shared no-op context manager after one module-global check and one
-ContextVar read — no allocation, no clock read, and (critically) no jax
-import anywhere in this module, so ``GEOMESA_TPU_NO_JAX=1`` keeps working.
-The bound is asserted by ``tests/test_obs.py``.
+ContextVar read — no allocation, no clock read, no jax. The bound is
+asserted by ``tests/test_obs.py``.
 
-Enable globally with :func:`enable` (or ``GEOMESA_TPU_TRACE=<path>`` in the
-environment — bench.py's ``--trace`` sets it), or per-call-tree with
-:func:`collect` (what ``DataStore.explain(..., analyze=True)`` uses).
-Completed root spans land in a bounded in-memory buffer; exporters
-(:mod:`geomesa_tpu.obs.export`) turn them into Chrome/Perfetto trace JSON.
+Profiler bridge: every recorded span also opens a
+``jax.profiler.TraceAnnotation`` named ``geomesa.<span name>`` for its
+lifetime, so a ``jax.profiler`` capture of the live system shows the
+program's spans as host events on the same clock as the device ops. jax
+is resolved lazily by the first recorded span, and never where
+``GEOMESA_TPU_NO_JAX=1`` is set: spans still record there, unbridged.
+The prefix keeps bridged events apart from annotations a caller opens
+itself under the span's bare name.
+
+Enable globally with :func:`enable` (or any non-empty
+``GEOMESA_TPU_TRACE`` in the environment, which child processes inherit),
+or per-call-tree with :func:`collect` (what ``DataStore.explain(...,
+analyze=True)`` uses). Completed root spans land in a bounded in-memory
+buffer; exporters (:mod:`geomesa_tpu.obs.export`) turn them into
+Chrome/Perfetto trace JSON.
 """
 
 from __future__ import annotations
@@ -76,6 +85,24 @@ _ids = itertools.count(1)
 # recorder so anomaly dumps fire only once the whole tree is closed
 _root_listeners: list = []
 
+# profiler bridge (module docstring): None until the first recorded span
+# resolves it, then jax.profiler.TraceAnnotation, or False where jax is
+# gated off or missing
+BRIDGE_PREFIX = "geomesa."
+_annotation = None
+
+
+def _resolve_annotation():
+    global _annotation
+    ann = False
+    if not os.environ.get("GEOMESA_TPU_NO_JAX"):
+        try:
+            from jax.profiler import TraceAnnotation as ann
+        except ImportError:  # no jax: spans stay unbridged
+            pass
+    _annotation = ann
+    return ann
+
 
 class Span:
     """One timed stage. Context manager; children attach automatically via
@@ -83,7 +110,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "attrs", "children",
-        "events", "t0_ns", "t1_ns", "thread_id", "_token",
+        "events", "t0_ns", "t1_ns", "thread_id", "_token", "_prof",
     )
 
     def __init__(self, name: str, attrs: dict, parent: "Span | None"):
@@ -105,6 +132,7 @@ class Span:
         self.t1_ns = 0
         self.thread_id = threading.get_ident()
         self._token = None
+        self._prof = None
 
     # -- timing ---------------------------------------------------------------
     @property
@@ -123,12 +151,20 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        ann = _annotation if _annotation is not None else _resolve_annotation()
+        if ann:
+            # name only: the attributes stay on the Span
+            self._prof = ann(BRIDGE_PREFIX + self.name)
+            self._prof.__enter__()
         self._token = _current.set(self)
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.t1_ns = time.perf_counter_ns()
+        if self._prof is not None:
+            self._prof.__exit__(exc_type, exc, tb)
+            self._prof = None
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         parent = None
@@ -560,7 +596,7 @@ class StageTimeline:
     __str__ = render
 
 
-# bench.py --trace / operator opt-in without code: enabling via environment
-# here means child worker processes (bench driver mode) inherit tracing
+# operator opt-in without code: enabling via the environment here means
+# child worker processes inherit tracing
 if os.environ.get("GEOMESA_TPU_TRACE"):
     enable()

@@ -606,46 +606,53 @@ class TpuBackend(ExecutionBackend):
         if dev.rows_per_shard % B != 0:
             raise ValueError(
                 f"residency not block-aligned: {dev.rows_per_shard} % {B}")
-        pair_q, pair_blk = intervals_to_block_pairs(intervals_list, B)
         empty = [np.empty(0, dtype=np.int64) for _ in range(nq)]
-        if len(pair_q) == 0:
-            return empty
-        # pairs processed per scan step: larger chunks amortize per-step
-        # overhead on accelerators (live gather memory = chunk × JOIN_BLOCK
-        # × 24 B); power of two so the padded budget always divides
-        chunk = int(os.environ.get("GEOMESA_SELECT_BLOCK_CHUNK", "8"))
-        if chunk < 1 or chunk & (chunk - 1):
-            raise ValueError(
-                f"GEOMESA_SELECT_BLOCK_CHUNK must be a power of two: {chunk}")
-        budget = pad_bucket(len(pair_q), minimum=chunk)
-        pq, pb = pad_block_pairs(pair_q, pair_blk, budget)
-        overlap = dev.kind == "bboxes"
-        payloads = [
-            self._payload(index.sft, e, overlap=overlap) for e in extractions
-        ]
-        # bucket the query-batch dimension too: every compile-time shape
-        # (nqp, budget, capacity) is a bucket, so naturally varying batch
-        # sizes reuse cached executables instead of recompiling per size.
-        # Padded query slots are never referenced by any pair. The planned
-        # steps split this axis over the mesh query axis, so the bucket must
-        # also divide by it (the pad_query_axis contract) — a pure power-of-
-        # two bucket fails dispatch on query_parallel=3 etc.
-        import math
+        # host staging before the first dispatch: (query, block) pairs,
+        # their padding, the payloads and the stacked batch
+        with obs.span("stage", queries=nq):
+            pair_q, pair_blk = intervals_to_block_pairs(intervals_list, B)
+            if len(pair_q) == 0:
+                return empty
+            # pairs processed per scan step: larger chunks amortize
+            # per-step overhead on accelerators (live gather memory =
+            # chunk × JOIN_BLOCK × 24 B); power of two so the padded
+            # budget always divides
+            chunk = int(os.environ.get("GEOMESA_SELECT_BLOCK_CHUNK", "8"))
+            if chunk < 1 or chunk & (chunk - 1):
+                raise ValueError(
+                    "GEOMESA_SELECT_BLOCK_CHUNK must be a power of two: "
+                    f"{chunk}")
+            budget = pad_bucket(len(pair_q), minimum=chunk)
+            pq, pb = pad_block_pairs(pair_q, pair_blk, budget)
+            overlap = dev.kind == "bboxes"
+            payloads = [
+                self._payload(index.sft, e, overlap=overlap)
+                for e in extractions
+            ]
+            # bucket the query-batch dimension too: every compile-time
+            # shape (nqp, budget, capacity) is a bucket, so naturally
+            # varying batch sizes reuse cached executables instead of
+            # recompiling per size. Padded query slots are never
+            # referenced by any pair. The planned steps split this axis
+            # over the mesh query axis, so the bucket must also divide by
+            # it (the pad_query_axis contract) — a pure power-of-two
+            # bucket fails dispatch on query_parallel=3 etc.
+            import math
 
-        from geomesa_tpu.parallel.mesh import QUERY_AXIS
+            from geomesa_tpu.parallel.mesh import QUERY_AXIS
 
-        nqp = math.lcm(pad_bucket(nq, minimum=4), mesh.shape[QUERY_AXIS])
-        boxes = np.stack(
-            [p[0] for p in payloads]
-            + [np.zeros_like(payloads[0][0])] * (nqp - nq)
-        )
-        times = np.stack(
-            [p[1] for p in payloads]
-            + [np.zeros_like(payloads[0][1])] * (nqp - nq)
-        )
-        from geomesa_tpu.obs.jaxmon import count_h2d
+            nqp = math.lcm(pad_bucket(nq, minimum=4), mesh.shape[QUERY_AXIS])
+            boxes = np.stack(
+                [p[0] for p in payloads]
+                + [np.zeros_like(payloads[0][0])] * (nqp - nq)
+            )
+            times = np.stack(
+                [p[1] for p in payloads]
+                + [np.zeros_like(payloads[0][1])] * (nqp - nq)
+            )
+            from geomesa_tpu.obs.jaxmon import count_h2d
 
-        count_h2d(pq, pb, boxes, times)  # per-batch payload staging
+            count_h2d(pq, pb, boxes, times)  # per-batch payload staging
         args = (
             *dev.spatial_cols(), jnp.int32(dev.n),
         )
@@ -677,23 +684,24 @@ class TpuBackend(ExecutionBackend):
                 hits = _rtledger.materialize(hits)
         # per-pair spans: a pair's rows sit in its OWNER shard's buffer,
         # consecutively in pair-index order (the device scan's write order)
-        blocks_per_shard = dev.rows_per_shard // B
-        out: list[list[np.ndarray]] = [[] for _ in range(nq)]
-        off = np.zeros(data_shards(mesh), dtype=np.int64)
-        for p in range(budget):
-            qid = int(pq[p])
-            if qid < 0:
-                continue
-            d = int(pb[p]) // blocks_per_shard
-            h = int(hits[p])
-            if h:
-                out[qid].append(buf[d, off[d]: off[d] + h])
-            off[d] += h
-        return [
-            np.concatenate(o).astype(np.int64) if o
-            else np.empty(0, dtype=np.int64)
-            for o in out
-        ]
+        with obs.span("unpack", pairs=len(pair_q)):
+            blocks_per_shard = dev.rows_per_shard // B
+            out: list[list[np.ndarray]] = [[] for _ in range(nq)]
+            off = np.zeros(data_shards(mesh), dtype=np.int64)
+            for p in range(budget):
+                qid = int(pq[p])
+                if qid < 0:
+                    continue
+                d = int(pb[p]) // blocks_per_shard
+                h = int(hits[p])
+                if h:
+                    out[qid].append(buf[d, off[d]: off[d] + h])
+                off[d] += h
+            return [
+                np.concatenate(o).astype(np.int64) if o
+                else np.empty(0, dtype=np.int64)
+                for o in out
+            ]
 
     @dispatch_budget(2)
     def _mesh_select_positions(
@@ -727,47 +735,52 @@ class TpuBackend(ExecutionBackend):
         memo_key = ("twopass", id(mesh), dev.rows_per_shard, dev.kind)
         memo = plan.exec_cache.get(memo_key) if plan is not None else None
         if memo is None:
-            mx = max_shard_candidates(intervals, dev.rows_per_shard, n_shards)
-            if mx == 0:
-                memo = (0, None, None, None, None)
-                if plan is not None:
-                    plan.exec_cache[memo_key] = memo
-                return np.empty(0, dtype=np.int64)
-            bucket = pad_bucket(mx)
-            idx, counts = split_intervals_by_shard(
-                intervals, dev.rows_per_shard, n_shards, bucket
-            )
-            boxes, times = self._payload(
-                index.sft, extraction, overlap=bbox_mode)
-            from geomesa_tpu.obs.jaxmon import count_h2d
-
-            count_h2d(idx, counts, boxes, times)  # per-query payload staging
-            memo = (
-                mx,
-                jnp.asarray(idx), jnp.asarray(counts),
-                jnp.asarray(boxes), jnp.asarray(times),
-            )
-            # memoize only payloads under the per-plan slot cap — a wide
-            # scan's (n_shards, bucket) split can reach MBs per plan and
-            # those re-derive per query (their cost is scan-dominated
-            # anyway). Memoized bytes ARE device residency: register them
-            # in the ledger under the "planmemo" group with the PLAN as
-            # owner, so the footprint shows in the residency gauges /
-            # budget headroom and unregisters itself when the plan cache
-            # drops the plan (LRU or state swap). Not pool-evictable by
-            # design: the per-plan cap bounds each entry and the plan
-            # cache's 128-entry LRU bounds the aggregate.
-            if (plan is not None
-                    and n_shards * bucket <= _EXEC_MEMO_MAX_SLOTS):
-                plan.exec_cache[memo_key] = memo
-                from geomesa_tpu.obs import devmon
-
-                devmon.ledger().register(
-                    getattr(index.sft, "name", "?"), index.name,
-                    "planmemo",
-                    sum(int(a.nbytes) for a in memo[1:]),
-                    owner=plan,
+            # host staging before the first dispatch: the per-shard
+            # candidate split, the payload, and their puts to the device
+            with obs.span("stage", intervals=len(intervals)):
+                mx = max_shard_candidates(
+                    intervals, dev.rows_per_shard, n_shards)
+                if mx == 0:
+                    memo = (0, None, None, None, None)
+                    if plan is not None:
+                        plan.exec_cache[memo_key] = memo
+                    return np.empty(0, dtype=np.int64)
+                bucket = pad_bucket(mx)
+                idx, counts = split_intervals_by_shard(
+                    intervals, dev.rows_per_shard, n_shards, bucket
                 )
+                boxes, times = self._payload(
+                    index.sft, extraction, overlap=bbox_mode)
+                from geomesa_tpu.obs.jaxmon import count_h2d
+
+                count_h2d(idx, counts, boxes, times)  # per-query staging
+                memo = (
+                    mx,
+                    jnp.asarray(idx), jnp.asarray(counts),
+                    jnp.asarray(boxes), jnp.asarray(times),
+                )
+                # memoize only payloads under the per-plan slot cap — a
+                # wide scan's (n_shards, bucket) split can reach MBs per
+                # plan and those re-derive per query (their cost is
+                # scan-dominated anyway). Memoized bytes ARE device
+                # residency: register them in the ledger under the
+                # "planmemo" group with the PLAN as owner, so the footprint
+                # shows in the residency gauges / budget headroom and
+                # unregisters itself when the plan cache drops the plan
+                # (LRU or state swap). Not pool-evictable by design: the
+                # per-plan cap bounds each entry and the plan cache's
+                # 128-entry LRU bounds the aggregate.
+                if (plan is not None
+                        and n_shards * bucket <= _EXEC_MEMO_MAX_SLOTS):
+                    plan.exec_cache[memo_key] = memo
+                    from geomesa_tpu.obs import devmon
+
+                    devmon.ledger().register(
+                        getattr(index.sft, "name", "?"), index.name,
+                        "planmemo",
+                        sum(int(a.nbytes) for a in memo[1:]),
+                        owner=plan,
+                    )
         mx, d_idx, d_counts, d_boxes, d_times = memo
         if mx == 0:
             return np.empty(0, dtype=np.int64)
@@ -809,6 +822,7 @@ class TpuBackend(ExecutionBackend):
         )
         pos = _rtledger.materialize(pos)
         hits = _rtledger.materialize(hits)
-        return np.concatenate(
-            [pos[d, : hits[d]] for d in range(n_shards)]
-        ).astype(np.int64)
+        with obs.span("unpack", shards=n_shards):
+            return np.concatenate(
+                [pos[d, : hits[d]] for d in range(n_shards)]
+            ).astype(np.int64)

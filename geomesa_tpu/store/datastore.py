@@ -1365,7 +1365,8 @@ class DataStore:
                 table, rows, density, stats_out, bin_data = reduce_result(
                     st.sft, empty, np.empty(0, dtype=np.int64), q
                 )
-            self._audit(type_name, q, 0.0, 0.0, 0)
+            with obs.span("telemetry"):
+                self._audit(type_name, q, 0.0, 0.0, 0)
             return QueryResult(
                 table, rows, density=density, stats=stats_out, bin_data=bin_data
             )
@@ -1527,7 +1528,8 @@ class DataStore:
         info = plan_box["info"]
         plan_ms = plan_box["plan_ms"]
         scan_ms = (_time.perf_counter() - t_start) * 1000.0 - plan_ms
-        self._audit(type_name, q, plan_ms, scan_ms, len(table), info=info)
+        with obs.span("telemetry"):
+            self._audit(type_name, q, plan_ms, scan_ms, len(table), info=info)
         if audit_epoch is not None:
             # shadow re-execution against the independent referee: the
             # LIVE answer (post-reduce fids) rides along so the check
@@ -1745,42 +1747,69 @@ class DataStore:
         from geomesa_tpu.ops.refine import MAX_BOXES, MAX_TIMES
 
         pending: list[tuple[int, tuple | None, bool]] = []
-        for i, q in enumerate(qs):
-            f = q.resolved_filter()
-            if (
-                not _pure_bbox_time(f, st.sft)
-                or _semantic_hints(q)
-                or q.auths is not None
-                or q.limit is not None
-                or q.start_index is not None
-            ):
-                continue
-            e = _extract(f, st.sft.geom_field, st.sft.dtg_field)
-            if viewport is not None and not e.disjoint:
-                vx1, vy1, vx2, vy2 = viewport
-                boxes = e.boxes if e.boxes is not None else [
-                    (-180.0, -90.0, 180.0, 90.0)
-                ]
-                clipped = []
-                for x1, y1, x2, y2 in boxes:
-                    nx1, ny1 = max(x1, vx1), max(y1, vy1)
-                    nx2, ny2 = min(x2, vx2), min(y2, vy2)
-                    if nx1 <= nx2 and ny1 <= ny2:
-                        clipped.append((nx1, ny1, nx2, ny2))
-                if not clipped:
-                    pending.append((i, None, True))
+        # the batch's planning: every query's parse, extraction and
+        # payload, under ONE span for the whole batch
+        with obs.span("plan", queries=len(qs)):
+            for i, q in enumerate(qs):
+                f = q.resolved_filter()
+                if (
+                    not _pure_bbox_time(f, st.sft)
+                    or _semantic_hints(q)
+                    or q.auths is not None
+                    or q.limit is not None
+                    or q.start_index is not None
+                ):
                     continue
-                e = _replace(e, boxes=clipped)
-            payload = (
-                None
-                if e.disjoint
-                else self.backend._payload(st.sft, e, overlap=overlap)
-            )
-            n_boxes = len(e.boxes) if e.boxes is not None else 1
-            n_times = len(e.intervals) if e.intervals is not None else 1
-            exactable = n_boxes <= MAX_BOXES and n_times <= MAX_TIMES
-            pending.append((i, payload, exactable))
+                e = _extract(f, st.sft.geom_field, st.sft.dtg_field)
+                if viewport is not None and not e.disjoint:
+                    vx1, vy1, vx2, vy2 = viewport
+                    boxes = e.boxes if e.boxes is not None else [
+                        (-180.0, -90.0, 180.0, 90.0)
+                    ]
+                    clipped = []
+                    for x1, y1, x2, y2 in boxes:
+                        nx1, ny1 = max(x1, vx1), max(y1, vy1)
+                        nx2, ny2 = min(x2, vx2), min(y2, vy2)
+                        if nx1 <= nx2 and ny1 <= ny2:
+                            clipped.append((nx1, ny1, nx2, ny2))
+                    if not clipped:
+                        pending.append((i, None, True))
+                        continue
+                    e = _replace(e, boxes=clipped)
+                payload = (
+                    None
+                    if e.disjoint
+                    else self.backend._payload(st.sft, e, overlap=overlap)
+                )
+                n_boxes = len(e.boxes) if e.boxes is not None else 1
+                n_times = len(e.intervals) if e.intervals is not None else 1
+                exactable = n_boxes <= MAX_BOXES and n_times <= MAX_TIMES
+                pending.append((i, payload, exactable))
         return pending
+
+    def _stage_batch(self, live, *extra):
+        """Host staging of a batched fan-out before its one dispatch: the
+        live queries' payloads stacked, the query axis padded to divide
+        the mesh query axis (with duplicates the caller discards), the
+        transfer accounted, and every array put on the device. ``live``:
+        [(query idx, (boxes, times))]; ``extra``: further per-query
+        arrays padded alongside. Returns (mesh, device arrays: boxes,
+        times, *extra)."""
+        import jax.numpy as jnp
+
+        from geomesa_tpu.obs.jaxmon import count_h2d
+        from geomesa_tpu.parallel.mesh import pad_query_axis
+
+        with obs.span("stage", queries=len(live)):
+            mesh = self.backend._get_mesh()
+            arrays, _ = pad_query_axis(
+                mesh,
+                np.stack([p[0] for _, p in live]),
+                np.stack([p[1] for _, p in live]),
+                *extra,
+            )
+            count_h2d(*arrays)  # per-batch payload staging
+            return mesh, tuple(jnp.asarray(a) for a in arrays)
 
     @dispatch_budget(2, signatures=("*:rows",))
     def select_many(self, type_name: str, queries) -> list:
@@ -1949,9 +1978,10 @@ class DataStore:
                     tail_ms = (_time.perf_counter() - tq0) * 1000.0
                     from geomesa_tpu.obs import devmon as _devmon
 
-                    self._audit(type_name, q, plan_ms / len(qs),
-                                shared_ms / len(idxs) + tail_ms, len(tbl),
-                                sig=_devmon.plan_signature(info, q))
+                    with obs.span("telemetry"):
+                        self._audit(type_name, q, plan_ms / len(qs),
+                                    shared_ms / len(idxs) + tail_ms, len(tbl),
+                                    sig=_devmon.plan_signature(info, q))
                 results[i] = QueryResult(
                     tbl, rws, info, density=density, stats=stats_out,
                     bin_data=bin_data,
@@ -1982,6 +2012,20 @@ class DataStore:
         queries, widened payloads, truncated candidate lanes, or a
         non-empty hot tier fall back to exact per-query execution.
         """
+        queries = list(queries)
+        # ONE batch span (plan, stage, jit, sync and telemetry children)
+        # and one SHARED roundtrip ledger: the batched dispatch and sync
+        # charge every member query's signature, as in select_many;
+        # per-query fallbacks open their own nested ledger inside query()
+        with obs.span("count_many", n_queries=len(queries)):
+            from geomesa_tpu.obs import ledger as _rtledger
+
+            with _rtledger.roundtrip():
+                return self._run_count_many(type_name, queries, loose)
+
+    def _run_count_many(self, type_name: str, queries, loose: bool):
+        from geomesa_tpu.obs import ledger as _rtledger
+
         st = self._state(type_name)
         qs = [
             Query(filter=q) if isinstance(q, (str, ast.Filter)) or q is None else q
@@ -2035,20 +2079,13 @@ class DataStore:
                 cached_batched_overlap_step,
             )
 
-            boxes = np.stack([p[0] for _, p in live])
-            times = np.stack([p[1] for _, p in live])
-            # one fused scan over the mesh-sharded columns, counts
-            # psum-merged over the data axis (P4 + P6); the query batch must
-            # divide the mesh query axis — pad with duplicates and discard
-            from geomesa_tpu.obs.jaxmon import count_h2d
-            from geomesa_tpu.parallel.mesh import pad_query_axis
-
-            mesh = self.backend._get_mesh()
-            (boxes, times), _ = pad_query_axis(mesh, boxes, times)
-            count_h2d(boxes, times)  # per-batch payload staging
             edge_pos = edge_hits = None
             cap = 512
             try:
+                # one fused scan over the mesh-sharded columns, counts
+                # psum-merged over the data axis (P4 + P6); the query batch
+                # is padded to divide the mesh query axis
+                mesh, (boxes, times) = self._stage_batch(live)
                 if not loose:
                     # ONE fused pass returns counts AND the boundary
                     # candidates — exact mode costs the same device scan
@@ -2061,29 +2098,26 @@ class DataStore:
                     )
                     col_args = (bbox_dev or dev).spatial_cols()
                     counts, edge_pos, edge_hits = gather(
-                        *col_args, jnp.int32(main_n),
-                        jnp.asarray(boxes), jnp.asarray(times),
+                        *col_args, jnp.int32(main_n), boxes, times,
                     )
-                    counts = np.asarray(counts)
-                    edge_pos = np.asarray(edge_pos)   # (Qp, D, cap)
-                    edge_hits = np.asarray(edge_hits)  # (Qp, D)
+                    counts = _rtledger.materialize(counts)
+                    edge_pos = _rtledger.materialize(edge_pos)  # (Qp, D, cap)
+                    edge_hits = _rtledger.materialize(edge_hits)  # (Qp, D)
                 elif bbox_dev is not None:
                     step = cached_batched_overlap_step(mesh, with_time=True)
-                    counts = np.asarray(
+                    counts = _rtledger.materialize(
                         step(
                             *bbox_dev.spatial_cols(),
-                            jnp.int32(main_n),
-                            jnp.asarray(boxes), jnp.asarray(times),
+                            jnp.int32(main_n), boxes, times,
                         )
                     )
                 else:
                     c = dev.cols
                     step = cached_batched_count_step(mesh)
-                    counts = np.asarray(
+                    counts = _rtledger.materialize(
                         step(
                             c["x"], c["y"], c["bins"], c["offs"],
-                            jnp.int32(main_n),
-                            jnp.asarray(boxes), jnp.asarray(times),
+                            jnp.int32(main_n), boxes, times,
                         )
                     )
             except Exception as e:  # noqa: BLE001 — failover to exact host path
@@ -2115,18 +2149,20 @@ class DataStore:
                             m = ast.residual_mask(f, main, rows)
                             corr = int((~m).sum())
                         out[i] = int(counts[k]) - corr
-        # batched queries still hit metrics + the audit trail
-        for i, _p, _ok in pending:
-            if out[i] is None:
-                continue  # device failover: the exact path audits these
-            self.metrics.counter("store.queries").inc()
-            self._audit(type_name, qs[i], 0.0, 0.0, out[i])
-            if (audit_epoch is not None
-                    and _obsaudit.eligible_select(qs[i])
-                    and (qs[i].hints.get("audit")
-                         or (_obsaudit.ENABLED and _obsaudit.sampled()))):
-                _obsaudit.get().enqueue_count(
-                    self, type_name, qs[i], audit_epoch, int(out[i]))
+        # batched queries still hit metrics + the audit trail (one
+        # telemetry span for the whole batch)
+        with obs.span("telemetry", queries=len(pending)):
+            for i, _p, _ok in pending:
+                if out[i] is None:
+                    continue  # device failover: the exact path audits these
+                self.metrics.counter("store.queries").inc()
+                self._audit(type_name, qs[i], 0.0, 0.0, out[i])
+                if (audit_epoch is not None
+                        and _obsaudit.eligible_select(qs[i])
+                        and (qs[i].hints.get("audit")
+                             or (_obsaudit.ENABLED and _obsaudit.sampled()))):
+                    _obsaudit.get().enqueue_count(
+                        self, type_name, qs[i], audit_epoch, int(out[i]))
         for i, q in enumerate(qs):
             if out[i] is None:
                 out[i] = _exact(q)
@@ -2531,9 +2567,12 @@ class DataStore:
         from geomesa_tpu.obs import audit as _obsaudit
 
         box: dict = {}
-        out = self._aggregate_many_impl(
-            type_name, queries, group_by=group_by, value_cols=value_cols,
-            now_ms=now_ms, audit_box=box)
+        queries = list(queries)
+        # ONE batch span: plan, stage, jit, sync and telemetry children
+        with obs.span("aggregate_many", n_queries=len(queries)):
+            out = self._aggregate_many_impl(
+                type_name, queries, group_by=group_by, value_cols=value_cols,
+                now_ms=now_ms, audit_box=box)
         if "epoch" in box and not _obsaudit.in_shadow():
             qs = box["qs"]
             for i, r in enumerate(out):
@@ -2647,8 +2686,9 @@ class DataStore:
                     out[i] = hit
                     self.metrics.counter("store.queries").inc()
                     self.metrics.counter("store.agg.cache_hits").inc()
-                    self._audit(type_name, q, 0.0, 0.0,
-                                int(hit["count"].sum()))
+                    with obs.span("telemetry"):
+                        self._audit(type_name, q, 0.0, 0.0,
+                                    int(hit["count"].sum()))
             from geomesa_tpu.ops import geoblocks as _geoblocks
             from geomesa_tpu.planning.planner import choose_agg_path
 
@@ -2681,7 +2721,8 @@ class DataStore:
                     self.metrics.counter("store.agg.pyramid_served").inc()
                     _observe_gagg(type_name, "gagg:pyramid",
                                   wall_ms=wall, rows=total)
-                    self._audit(type_name, q, 0.0, wall, total)
+                    with obs.span("telemetry"):
+                        self._audit(type_name, q, 0.0, wall, total)
                     key = cache_ctx["keys"].get(i)
                     if key is not None:
                         self.agg_cache.put(type_name, key, epoch, res)
@@ -2730,19 +2771,13 @@ class DataStore:
         t_scan0 = _time.perf_counter()
         import jax.numpy as jnp
 
-        from geomesa_tpu.parallel.mesh import pad_query_axis
+        from geomesa_tpu.obs import ledger as _rtledger
         from geomesa_tpu.parallel.query import cached_grouped_agg_step
 
-        mesh = self.backend._get_mesh()
         G_pad = 1 << max(0, (G - 1).bit_length())
         cap = 512
-        boxes = np.stack([p[0] for _, p in live])
-        times = np.stack([p[1] for _, p in live])
-        (boxes, times), _ = pad_query_axis(mesh, boxes, times)
-        from geomesa_tpu.obs.jaxmon import count_h2d
-
-        count_h2d(boxes, times)  # per-batch payload staging
         try:
+            mesh, (boxes, times) = self._stage_batch(live)
             step = cached_grouped_agg_step(
                 mesh, G_pad, len(value_cols), cap,
                 with_ttl=cutoff_ms is not None, overlap=overlap,
@@ -2763,11 +2798,10 @@ class DataStore:
             with self.backend.pool.pinned(type_name, dev_name):
                 res = step(
                     *dev.spatial_cols(), dev_gid, dev_rowid,
-                    dev_vals, jnp.int32(main_n), jnp.asarray(boxes),
-                    jnp.asarray(times), *ttl_args,
+                    dev_vals, jnp.int32(main_n), boxes, times, *ttl_args,
                 )
                 cnt, first, vcnt, vsum, vmin, vmax, epos, ehits = map(
-                    np.asarray, res
+                    _rtledger.materialize, res
                 )
         except Exception as e:  # noqa: BLE001 — failover to the host fold
             if not self._is_device_error(e):
@@ -2802,9 +2836,10 @@ class DataStore:
             self.metrics.counter("store.queries").inc()
             # audit the POST-correction total (edge + delta rows included),
             # matching what count_many/density_many record
-            self._audit(
-                type_name, qs[i], 0.0, 0.0, int(out[i]["count"].sum())
-            )
+            with obs.span("telemetry"):
+                self._audit(
+                    type_name, qs[i], 0.0, 0.0, int(out[i]["count"].sum())
+                )
             if cache_ctx is not None:
                 key = cache_ctx["keys"].get(i)
                 if key is not None:
@@ -2966,6 +3001,14 @@ class DataStore:
         rows may differ from the exact float path); ``loose=False`` forces
         the exact path for every query.
         """
+        queries = list(queries)
+        # ONE batch span: plan, stage, jit, sync and telemetry children
+        with obs.span("density_many", n_queries=len(queries)):
+            return self._run_density_many(
+                type_name, queries, bbox, width, height, loose)
+
+    def _run_density_many(self, type_name: str, queries, bbox, width: int,
+                          height: int, loose: bool):
         st = self._state(type_name)
         qs = [
             Query(filter=q) if isinstance(q, (str, ast.Filter)) or q is None else q
@@ -3009,7 +3052,7 @@ class DataStore:
             import jax.numpy as jnp
 
             from geomesa_tpu.curve.normalize import lat as norm_lat, lon as norm_lon
-            from geomesa_tpu.parallel.mesh import pad_query_axis
+            from geomesa_tpu.obs import ledger as _rtledger
             from geomesa_tpu.parallel.query import cached_batched_density_step
             from geomesa_tpu.store.backends import REFINE_PRECISION
 
@@ -3021,21 +3064,14 @@ class DataStore:
                  int(nlat.normalize(y1)), int(nlat.normalize(y2))],
                 dtype=np.int32,
             )
-            boxes = np.stack([p[0] for _, p in live])
-            times = np.stack([p[1] for _, p in live])
             gbs = np.broadcast_to(gb, (len(live), 4)).copy()
-            mesh = self.backend._get_mesh()
-            (boxes, times, gbs), _ = pad_query_axis(mesh, boxes, times, gbs)
-            from geomesa_tpu.obs.jaxmon import count_h2d
-
-            count_h2d(boxes, times, gbs)  # per-batch payload staging
             c = dev.cols
             try:
-                grids = np.asarray(
+                mesh, (boxes, times, gbs) = self._stage_batch(live, gbs)
+                grids = _rtledger.materialize(
                     cached_batched_density_step(mesh, width, height)(
                         c["x"], c["y"], c["bins"], c["offs"],
-                        jnp.int32(main_n),
-                        jnp.asarray(boxes), jnp.asarray(times), jnp.asarray(gbs),
+                        jnp.int32(main_n), boxes, times, gbs,
                     )
                 )
             except Exception as e:  # noqa: BLE001 — failover to exact path
@@ -3048,11 +3084,12 @@ class DataStore:
                 self._note_device_ok()
                 for k, (i, _) in enumerate(live):
                     out[i] = grids[k].astype(np.float64)
-        for i, _p, _ok in pending:
-            if out[i] is None:
-                continue
-            self.metrics.counter("store.queries").inc()
-            self._audit(type_name, qs[i], 0.0, 0.0, int(out[i].sum()))
+        with obs.span("telemetry", queries=len(pending)):
+            for i, _p, _ok in pending:
+                if out[i] is None:
+                    continue
+                self.metrics.counter("store.queries").inc()
+                self._audit(type_name, qs[i], 0.0, 0.0, int(out[i].sum()))
         for i, q in enumerate(qs):
             if out[i] is None:
                 out[i] = _exact(q)
@@ -3213,9 +3250,14 @@ class DataStore:
             return
         from geomesa_tpu.utils.audit import QueryEvent, now_millis
         hints = ", ".join(f"{k}={v!r}" for k, v in sorted(q.hints.items()))
-        # audit↔trace join: the innermost live span is this query's (the
-        # "query" span in query()/select_many); empty when tracing is off
+        # audit↔trace join: the event names this query's span (the "query"
+        # span in query()/select_many), the parent of the "telemetry" span
+        # this call runs in; empty when tracing is off
         sp = obs.current()
+        span_id = ""
+        if sp is not None:
+            span_id = (sp.parent_id if sp.name == "telemetry" and sp.parent_id
+                       else sp.span_id)
         self.audit_writer.write_event(
             QueryEvent(
                 store_type=type(self.backend).__name__,
@@ -3228,7 +3270,7 @@ class DataStore:
                 scan_time_ms=scan_ms,
                 hits=hits,
                 trace_id=sp.trace_id if sp is not None else "",
-                span_id=sp.span_id if sp is not None else "",
+                span_id=span_id,
             )
         )
 
