@@ -155,9 +155,11 @@ def batched_count(x, y, bins, offs, base, true_n, boxes, times, *,
       x, y, bins, offs: (N,) int32 sorted columns (device-resident slice).
       base: () int32 — global row offset of this slice (shard id × slice len).
       true_n: () int32 — global unpadded row count (validity bound).
-      boxes: (Q, MAX_BOXES, 4) int32 [xlo, xhi, ylo, yhi] inclusive, padded
-        slots made always-false by :func:`geomesa_tpu.ops.refine.pack_boxes`.
-      times: (Q, MAX_TIMES, 4) int32 [bin_lo, off_lo, bin_hi, off_hi].
+      boxes: (Q, B, 4) int32 [xlo, xhi, ylo, yhi] inclusive, any slot
+        count B (a compile-time shape: each slot is one unrolled test);
+        padded slots made always-false by
+        :func:`geomesa_tpu.ops.refine.pack_boxes`.
+      times: (Q, T, 4) int32 [bin_lo, off_lo, bin_hi, off_hi], any T.
 
     Returns:
       (Q,) int32 per-query match counts for this slice.

@@ -91,6 +91,43 @@ def pack_times(times_i32: np.ndarray | None, slots: int = MAX_TIMES) -> np.ndarr
     return np.vstack([t, pad])
 
 
+def _live_slots(payload: np.ndarray, pad: np.ndarray) -> np.ndarray:
+    """(Q,) per query: slots up to and including its last non-pad slot."""
+    real = (payload != pad).any(axis=2)  # (Q, S)
+    last = payload.shape[1] - np.argmax(real[:, ::-1], axis=1)
+    return np.where(real.any(axis=1), last, 0)
+
+
+def trim_slots(
+    boxes: np.ndarray, times: np.ndarray, overlap: bool = False
+) -> tuple[np.ndarray, np.ndarray, int]:
+    """Stacked batch payloads (Q, B, 4) / (Q, T, 4) → the same queries
+    over only the slot columns some query uses: the trailing columns that
+    are padding in every query are dropped, each axis kept at its live
+    maximum rounded up to a power of two (at least 1, at most its width),
+    so the kernels' static slot loops run the batch's real slots and the
+    set of compiled shapes stays small.
+
+    Exact: ``pack_*`` put padding after the real slots, a pad slot matches
+    nothing, and a real slot equal to the pad matches nothing either.
+    ``overlap`` selects the box pad as in :func:`pack_boxes`; the time pad
+    is unsatisfiable in both modes, while the no-constraint time sentinel
+    is not a pad and stays. Returns (boxes (Q, B', 4), times (Q, T', 4),
+    the batch's live box+time slots)."""
+    live_b = _live_slots(boxes, _BOX_PAD_OVERLAP if overlap else _BOX_PAD)
+    live_t = _live_slots(times, _TIME_PAD)
+
+    def width(live: np.ndarray, slots: int) -> int:
+        n = int(live.max(initial=0))
+        return min(slots, 1 << max(n - 1, 0).bit_length())
+
+    return (
+        boxes[:, : width(live_b, boxes.shape[1])],
+        times[:, : width(live_t, times.shape[1])],
+        int(live_b.sum() + live_t.sum()),
+    )
+
+
 @jax.jit
 def refine_points(x, y, bins, offs, idx, count, boxes, times):
     """Fused gather + int-domain bbox/time refine over candidate slots.

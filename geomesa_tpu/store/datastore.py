@@ -2074,11 +2074,24 @@ class DataStore:
         if live:
             import jax.numpy as jnp
 
+            from geomesa_tpu.ops.refine import trim_slots
             from geomesa_tpu.parallel.query import (
                 cached_batched_count_step,
                 cached_batched_overlap_step,
             )
 
+            # the scan tests only the slot columns some query of the batch
+            # uses: a one-box, one-window batch pays for 1 + 1 slots, not
+            # MAX_BOXES + MAX_TIMES
+            live_b, live_t, n_live = trim_slots(
+                np.stack([p[0] for _, p in live]),
+                np.stack([p[1] for _, p in live]),
+                overlap=bbox_dev is not None,
+            )
+            live = [(i, (live_b[k], live_t[k])) for k, (i, _) in enumerate(live)]
+            self.metrics.counter("store.count_many.slots_live").inc(n_live)
+            self.metrics.counter("store.count_many.slots_scanned").inc(
+                len(live) * (live_b.shape[1] + live_t.shape[1]))
             edge_pos = edge_hits = None
             cap = 512
             try:

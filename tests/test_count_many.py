@@ -260,6 +260,94 @@ class TestCountMany:
         assert len(ds2.audit_writer.query_events("a")) == 2
 
 
+_DAY = "2017-07-0{}T00:00:00Z"
+_SLOT_BATCHES = {
+    # every CQL one box and one window: the scan tests 1 + 1 slots
+    "one_slot": [
+        f"BBOX(geom, {x}, {y}, {x + 30}, {y + 25}) AND dtg DURING "
+        f"{_DAY.format(d)}/{_DAY.format(d + 3)}"
+        for x, y, d in ((-120, -50, 1), (-40, -20, 2), (10, 0, 3), (60, 20, 4))
+    ],
+    # an antimeridian box (two slots), no time constraint, no box
+    "mixed": [
+        f"BBOX(geom, -60, -30, 0, 30) AND dtg DURING "
+        f"{_DAY.format(1)}/{_DAY.format(5)}",
+        f"BBOX(geom, 150, -40, -150, 40) AND dtg DURING "
+        f"{_DAY.format(2)}/{_DAY.format(8)}",
+        "BBOX(geom, 20, -10, 80, 60)",
+        f"dtg DURING {_DAY.format(3)}/{_DAY.format(4)}",
+    ],
+    # the OR is not a pure bbox+time conjunction: it runs per query while
+    # the rest of the batch stays batched
+    "or_boxes": [
+        "BBOX(geom, -100, -40, -60, 0) OR BBOX(geom, 40, 0, 90, 50)",
+        f"BBOX(geom, -20, -20, 20, 20) AND dtg DURING "
+        f"{_DAY.format(1)}/{_DAY.format(6)}",
+        "BBOX(geom, 160, -60, -160, 60)",
+    ],
+}
+# (live box+time slots, Q x (B' + T') scanned) of each batch's batched part
+_SLOT_COUNTERS = {"one_slot": (8, 8), "mixed": (9, 12), "or_boxes": (5, 6)}
+
+
+@pytest.fixture(scope="module")
+def slot_stores():
+    """A point store and an XZ (bbox-overlap) store of horizontal segments,
+    whose bboxes ARE their geometries, so loose counts equal exact ones;
+    instants sit half a second off every whole second, never on a
+    window's quantized endpoint."""
+    from geomesa_tpu.geometry.types import LineString
+
+    rng = np.random.default_rng(21)
+    n = 3_000
+    lon = rng.uniform(-175, 170, n)
+    lat = rng.uniform(-80, 80, n)
+    dtg = T0 + rng.integers(0, 10 * 86_400, n) * 1000 + 500
+    fids = [str(i) for i in range(n)]
+    pts = DataStore(backend="tpu")
+    pts.create_schema("p", "dtg:Date,*geom:Point")
+    pts.write("p", [{"dtg": int(t), "geom": Point(float(x), float(y))}
+                    for x, y, t in zip(lon, lat, dtg)], fids=fids)
+    pts.compact("p")
+    xz = DataStore(backend="tpu")
+    xz.create_schema("s", "dtg:Date,*geom:LineString")
+    xz.write("s", [
+        {"dtg": int(t),
+         "geom": LineString([(float(x), float(y)), (float(x) + 4.0, float(y))])}
+        for x, y, t in zip(lon, lat, dtg)
+    ], fids=fids)
+    xz.compact("s")
+    return {"point": (pts, "p"), "xz": (xz, "s")}
+
+
+class TestCountManySlots:
+    """count_many scans only the slot columns its batch uses: every mode
+    and store layout answers as the per-query exact path and as the
+    untrimmed full-width scan."""
+
+    @pytest.mark.parametrize("batch", sorted(_SLOT_BATCHES))
+    @pytest.mark.parametrize("loose", [True, False])
+    @pytest.mark.parametrize("layout", ["point", "xz"])
+    def test_trimmed_batches_match_exact(self, slot_stores, monkeypatch,
+                                         layout, loose, batch):
+        from geomesa_tpu.ops import refine
+
+        store, t = slot_stores[layout]
+        qs = _SLOT_BATCHES[batch]
+        want = [store.query(t, q).count for q in qs]
+        live = store.metrics.counter("store.count_many.slots_live")
+        scanned = store.metrics.counter("store.count_many.slots_scanned")
+        before = (live.count, scanned.count)
+        got = store.count_many(t, qs, loose=loose)
+        assert got == want, (got, want)
+        assert sum(got) > 0
+        assert (live.count - before[0], scanned.count - before[1]) \
+            == _SLOT_COUNTERS[batch]
+        monkeypatch.setattr(refine, "trim_slots",
+                            lambda b, tt, overlap=False: (b, tt, 0))
+        assert store.count_many(t, qs, loose=loose) == got
+
+
 class TestDensityMany:
     def _stores(self, n=4000, seed=13):
         from geomesa_tpu.schema.sft import parse_spec

@@ -12,7 +12,14 @@ import pytest
 import geomesa_tpu  # noqa: F401
 from geomesa_tpu.curve import zorder
 from geomesa_tpu.ops.pallas_kernels import batched_count, z2_encode, z3_encode
-from geomesa_tpu.ops.refine import MAX_BOXES, MAX_TIMES, pack_boxes, pack_times
+from geomesa_tpu.ops.refine import (
+    MAX_BOXES,
+    MAX_TIMES,
+    pack_boxes,
+    pack_times,
+    trim_slots,
+    unsat_rows,
+)
 
 
 def _referee(x, y, b, o, boxes, times):
@@ -97,6 +104,90 @@ class TestBatchedCount:
         )
         ref = _referee(x, y, b, o, boxes, times)
         assert (got == ref).all()
+
+
+def _slot_payload(rng, n_boxes, n_times, q=5):
+    """Query 0 packs ``n_boxes`` boxes and ``n_times`` windows (None: no
+    time constraint) at full width; the rest one box and one window."""
+    boxes, times = [], []
+    for k in range(q):
+        nb, nt = (n_boxes, n_times) if k == 0 else (1, 1)
+        bx = np.sort(rng.integers(0, 2**31 - 1, (nb, 2, 2)), axis=2)
+        boxes.append(pack_boxes(bx.reshape(nb, 4).astype(np.int32)))
+        if nt is None:
+            times.append(pack_times(None))
+            continue
+        bins = np.sort(rng.integers(0, 50, (nt, 2)), axis=1)
+        offs = rng.integers(0, 86_400_000, (nt, 2))
+        times.append(pack_times(np.stack(
+            [bins[:, 0], offs[:, 0], bins[:, 1], offs[:, 1]], axis=1
+        ).astype(np.int32)))
+    return np.stack(boxes), np.stack(times)
+
+
+class TestTrimSlots:
+    @pytest.mark.parametrize("n_boxes,n_times,want", [
+        (1, 1, (1, 1)),
+        (3, 5, (4, 8)),
+        (9, 20, (1, 1)),  # both widened to one enveloping slot by pack_*
+        (1, None, (1, 1)),
+        (3, None, (4, 1)),
+        (9, 5, (1, 8)),
+    ])
+    def test_trimmed_scan_matches_full(self, rng, cols, n_boxes, n_times,
+                                       want):
+        x, y, b, o = cols
+        boxes, times = _slot_payload(rng, n_boxes, n_times)
+        tb, tt, _ = trim_slots(boxes, times)
+        assert (tb.shape[1], tt.shape[1]) == want
+        full = np.asarray(
+            batched_count(x, y, b, o, 0, len(x), boxes, times, interpret=True)
+        )
+        trimmed = np.asarray(
+            batched_count(x, y, b, o, 0, len(x), tb, tt, interpret=True)
+        )
+        assert (trimmed == full).all()
+        assert (full == _referee(x, y, b, o, boxes, times)).all()
+
+    @pytest.mark.parametrize("overlap", [False, True])
+    def test_live_counts_and_widths(self, overlap):
+        one = np.array([[0, 9, 0, 9]], np.int32)
+        three = np.array([[0, 1, 0, 1], [2, 3, 2, 3], [4, 5, 4, 5]], np.int32)
+        boxes = np.stack([pack_boxes(one, overlap=overlap),
+                          pack_boxes(three, overlap=overlap)])
+        times = np.stack([pack_times(None),
+                          pack_times(np.array([[0, 0, 1, 1]] * 2, np.int32))])
+        tb, tt, live = trim_slots(boxes, times, overlap=overlap)
+        assert tb.shape == (2, 4, 4) and tt.shape == (2, 2, 4)
+        assert live == (1 + 3) + (1 + 2)
+        # the kept columns are the leading ones, unchanged
+        assert (tb == boxes[:, :4]).all() and (tt == times[:, :2]).all()
+
+    def test_each_mode_drops_only_its_own_pad(self):
+        """[1, 0, 1, 0] is the point-mode pad but NOT empty under interval
+        overlap: an overlap batch keeps it as a live slot."""
+        b = np.array([[0, 9, 0, 9], [1, 0, 1, 0]], np.int32)
+        times = np.stack([pack_times(None)])
+        for overlap, width in ((True, 2), (False, 1)):
+            boxes = np.stack([pack_boxes(b, overlap=overlap)])
+            tb = trim_slots(boxes, times, overlap=overlap)[0]
+            assert tb.shape[1] == width, overlap
+
+    def test_real_slot_after_a_pad_equal_slot_is_kept(self):
+        """Trimming keeps everything up to the LAST live slot: a real slot
+        that happens to equal the pad never hides one after it."""
+        b = np.array([[0, 9, 0, 9], [1, 0, 1, 0], [3, 7, 3, 7]], np.int32)
+        boxes = np.stack([pack_boxes(b)])
+        times = np.stack([pack_times(None)])
+        tb, _, live = trim_slots(boxes, times)
+        assert tb.shape[1] == 4 and live == 3 + 1
+
+    def test_all_pad_query_keeps_one_slot(self):
+        """The unsatisfiable payload (every slot a pad) trims to one slot
+        per axis and still matches nothing."""
+        ub, ut = unsat_rows(MAX_BOXES, MAX_TIMES)
+        tb, tt, live = trim_slots(ub[None], ut[None])
+        assert tb.shape == (1, 1, 4) and tt.shape == (1, 1, 4) and live == 0
 
 
 class TestZEncode:

@@ -109,11 +109,11 @@ def _reducers(hlo: str) -> set:
 
 # -- the four Pallas kernels, one chip ---------------------------------------
 
-def _kernel_args(one, n_q: int):
+def _kernel_args(one, n_q: int, boxes: int = BOXES, times: int = TIMES):
     i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)  # noqa: E731
     col = i32((ROWS,))
     return (col, col, col, col, i32(()), i32(()),
-            i32((n_q, BOXES, 4)), i32((n_q, TIMES, 4)))
+            i32((n_q, boxes, 4)), i32((n_q, times, 4)))
 
 
 @pytest.mark.parametrize("kernel", ["batched_count", "batched_count_hits",
@@ -131,6 +131,19 @@ def test_pallas_kernel_compiles(topo, kernel):
         u32 = jax.ShapeDtypeStruct((ROWS,), jnp.uint32, sharding=one)
         args = (u32,) * (3 if kernel == "z3_encode" else 2)
     assert "tpu_custom_call" in _compile(fn, *args)
+
+
+@pytest.mark.parametrize("boxes,times", [(BOXES, TIMES), (1, 1)])
+def test_batched_count_slot_shapes_compile(topo, boxes, times):
+    """``count_many`` trims its payloads to the batch's live slots: the
+    one-box, one-window shape compiles beside the full width."""
+    from jax.sharding import SingleDeviceSharding
+
+    from geomesa_tpu.ops.pallas_kernels import batched_count
+
+    one = SingleDeviceSharding(topo.devices[0])
+    args = _kernel_args(one, Q, boxes, times)
+    assert "tpu_custom_call" in _compile(batched_count, *args)
 
 
 # -- sharded steps of the store's query path ---------------------------------
