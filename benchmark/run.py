@@ -17,9 +17,12 @@ freed, and every answer of the window compared with the plain reference.
 The last stdout line is one JSON object; the numbers compared, each with
 its limit, are the last lines of stderr and the result's last key.
 
-No TPU, or fewer chips than the cell asks for: exit 3 and no result.
-``--rehearse`` runs the configuration's tiny ``rehearse`` size on
-whatever JAX finds (the CPU here) and names that device.
+The store is built on a mesh of the cell's ``chips`` devices. No TPU,
+fewer chips than the cell asks for, or an index that set-up leaves on
+another number of devices: exit 3 and no result. ``--rehearse`` runs the
+configuration's tiny ``rehearse`` size on whatever JAX finds (the CPU
+here), on the cell's chips or as many devices as there are, and names
+that device.
 """
 
 from __future__ import annotations
@@ -208,7 +211,11 @@ def run(args) -> int:
     phases = {"start_s": time.perf_counter() - T_START}
     data = gen.generate(cfg, args.seed)
     phases["data_s"] = time.perf_counter() - T_START
-    ds = store.build_store(cfg, data)
+    try:
+        ds = store.build_store(cfg, data, chips)
+    except store.NotResident as e:
+        print(f"benchmark: {e}", file=sys.stderr)
+        return 3
     phases["build_s"] = time.perf_counter() - T_START
     reqs = traffic.Traffic(mix, cfg, data, args.seed, traffic.STREAM_WINDOW)
     warm = warm_up(ds, type_name, call, reqs, clock, float(args.seconds),
@@ -239,7 +246,9 @@ def run(args) -> int:
     window_compiles = clock.backend_compiles - compiles_before
     routes = {k: (n - routes_before.get(k, (0, 0))[0], p50)
               for k, (n, p50) in route_counts(type_name).items()}
-    memory_peak = store.peak_hbm(dev)
+    peaks = store.peak_hbm(store.mesh_devices(ds))
+    memory_peak = max((b for b in peaks if b is not None), default=None)
+    z3_devices = store.residency(ds, type_name)
     health = store.device_health(ds)
     n_cqls = sum(len(r) for r in requests)
 
@@ -310,6 +319,7 @@ def run(args) -> int:
             "cqls": n_cqls, "window_s": elapsed, "warm_requests": warm,
             "window_compiles": window_compiles, "reference_s": ref_s,
             "setup_s": setup_s, "phases_at_s": phases,
+            "memory_peak_bytes_by_device": peaks, "z3_devices": z3_devices,
             "window_routes": routes,
             "compile": clock.report()}
     print(json.dumps(info), file=sys.stderr)

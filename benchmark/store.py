@@ -1,10 +1,10 @@
 """Set-up and health of the system under test.
 
-Copied from ``chip_smoke.py`` (PR 21) so that no later PR moves them:
-the store build (``FeatureTable.from_columns`` → ``DataStore`` on an
-explicit one-device mesh → ``write``, which compacts past its threshold,
-→ ``compact``), the compile clock, the device-health counters and the
-peak-HBM reading.
+Copied from ``chip_smoke.py`` so that no later PR moves them: the store
+build (``FeatureTable.from_columns`` → ``DataStore`` on an explicit mesh
+of the cell's ``chips`` devices → ``write``, which compacts past its
+threshold, → ``compact``) and the residency check after it, the compile
+clock, the device-health counters and the peak-HBM reading.
 """
 
 from __future__ import annotations
@@ -46,9 +46,15 @@ class CompileClock:
                 **self.events}
 
 
-def build_store(cfg: dict, data):
-    """A one-chip ``DataStore`` holding ``data`` under the configuration's
-    spec, written and compacted as a bulk ingest is."""
+class NotResident(RuntimeError):
+    """The index does not live on the devices the cell asks for."""
+
+
+def build_store(cfg: dict, data, chips: int):
+    """A ``DataStore`` on a mesh of the first ``chips`` devices holding
+    ``data`` under the configuration's spec, written and compacted as a
+    bulk ingest is. Raises ``NotResident`` unless the z3 index then lives
+    on exactly ``chips`` devices: a store on fewer is not the deployment."""
     from geomesa_tpu.parallel.mesh import make_mesh
     from geomesa_tpu.schema.columnar import Column, FeatureTable, point_column
     from geomesa_tpu.schema.sft import parse_spec
@@ -65,11 +71,28 @@ def build_store(cfg: dict, data):
         else:
             cols[a.name] = Column(a.type, data.attrs[a.name])
     table = FeatureTable.from_columns(sft, data.fids, cols)
-    ds = DataStore(backend=TpuBackend(mesh=make_mesh(1)))
+    # an explicit mesh: the run uses exactly `chips` devices even on a
+    # host with more
+    ds = DataStore(backend=TpuBackend(mesh=make_mesh(chips)))
     ds.create_schema(sft)
     ds.write(cfg["type_name"], table)
     ds.compact(cfg["type_name"])
+    ids = residency(ds, cfg["type_name"])
+    if len(ids) != chips:
+        ds.close()
+        raise NotResident(f"z3 index resident on devices {ids}, want {chips}")
     return ds
+
+
+def residency(ds, type_name: str, index: str = "z3") -> list:
+    """Ids of the devices holding the resident index's columns."""
+    return sorted({d.id for c in resident_columns(ds, type_name, index).values()
+                   for d in c.devices()})
+
+
+def mesh_devices(ds) -> list:
+    """The devices of the store's mesh."""
+    return list(ds.backend._get_mesh().devices.flat)
 
 
 def resident_columns(ds, type_name: str, index: str = "z3") -> dict:
@@ -89,6 +112,7 @@ def device_health(ds) -> dict:
     }
 
 
-def peak_hbm(device) -> int | None:
-    stats = device.memory_stats() or {}
-    return stats.get("peak_bytes_in_use")
+def peak_hbm(devices) -> list:
+    """``peak_bytes_in_use`` of each device, None where the backend
+    reports none (the CPU)."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]

@@ -1,14 +1,20 @@
-"""The trace reduction on a small trace recorded on one TPU v5 lite
-(``data/tpu_tiny.xplane.pb``: three ``bench.request`` annotations, each
-around a jitted reduction and a jitted sort of 2**20 floats)."""
+"""The trace reduction on small traces recorded on TPU v5 lite chips
+(``data/tpu_tiny.xplane.pb``, one chip: three ``bench.request``
+annotations, each around a jitted reduction and a jitted sort of 2**20
+floats; ``data/tpu4_tiny.xplane.pb``, four chips: the same with the
+floats sharded over the chips, so the reduction ends in an all-reduce
+and the sort gathers), and on synthetic traces of one and two device
+planes."""
 
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
 from benchmark import trace_reduce
 
 TRACE = str(Path(__file__).parent / "data" / "tpu_tiny.xplane.pb")
+TRACE4 = str(Path(__file__).parent / "data" / "tpu4_tiny.xplane.pb")
 
 
 def _union_by_hand(intervals, lo, hi):
@@ -98,3 +104,101 @@ def test_cpu_trace_has_no_device_plane(tmp_path):
         f(jnp.ones(8)).block_until_ready()
     jax.profiler.stop_trace()
     assert trace_reduce.reduce(trace_reduce.find_xplane(tmp_path)) is None
+
+
+def _span(name, t0, t1, children=()):
+    return SimpleNamespace(name=name, t0_ns=t0, t1_ns=t1, children=list(children))
+
+
+def _plane(name, line, events):
+    evs = [SimpleNamespace(name=n, start_ns=s, end_ns=e) for n, s, e in events]
+    return SimpleNamespace(name=name, lines=[SimpleNamespace(name=line, events=evs)])
+
+
+def _roots(marks):
+    """One span tree a request, on the trace's own clock: the request,
+    with `plan` over its first third."""
+    return [_span("bench.request", s, e, [_span("plan", s, s + (e - s) // 3)])
+            for s, e in marks]
+
+
+def _labels_of_one_plane(pd, starts, roots):
+    """The idle labels as read from the first device plane alone."""
+    marks = trace_reduce.anchors(pd)
+    lo, hi = marks[0][0], marks[-1][1]
+    ops = trace_reduce.device_ops(pd)
+    first = sorted({o[3] for o in ops})[0]
+    iv = [(s, e) for _, s, e, plane in ops if plane == first]
+    offset = marks[0][0] - starts[0]
+    seg = trace_reduce.label_segments(trace_reduce.span_intervals(roots, offset))
+    return dict(trace_reduce.gaps_by_label(trace_reduce.idle_gaps(iv, lo, hi), seg))
+
+
+def test_one_recorded_plane_labels_as_before():
+    import jax
+
+    pd = jax.profiler.ProfileData.from_file(TRACE)
+    marks = trace_reduce.anchors(pd)
+    roots, starts = _roots(marks), [s for s, _ in marks]
+    out = trace_reduce.reduce(TRACE, starts, roots)
+    assert dict(out["breakdown"]["idle_gaps"]) == _labels_of_one_plane(pd, starts, roots)
+
+
+# two requests, [0, 100) under `plan` for its first half and [100, 200)
+# under `reduce` for its second half; chip 0 runs [50, 150), chip 1 runs
+# [0, 50) and [150, 200), so chip 0 idles under plan and reduce, chip 1
+# under the requests' own spans
+HOST = _plane("/host:CPU", "python", [("bench.request", 0, 100),
+                                      ("bench.request", 100, 200)])
+CHIP0 = _plane("/device:TPU:0", trace_reduce.OPS_LINE,
+               [("%fusion.1 = f32[8]", 50, 100), ("%fusion.1 = f32[8]", 100, 150)])
+CHIP1 = _plane("/device:TPU:1", trace_reduce.OPS_LINE,
+               [("%fusion.1 = f32[8]", 0, 50), ("%all-reduce.2 = f32[8]", 150, 200)])
+ROOTS = [_span("bench.request", 0, 100, [_span("plan", 0, 50)]),
+         _span("bench.request", 100, 200, [_span("reduce", 150, 200)])]
+STARTS = [0, 100]
+
+
+@pytest.mark.parametrize("planes, want", [
+    ([CHIP0], {"plan": 50, "reduce": 50}),
+    ([CHIP0, CHIP1], {"plan": 25, "reduce": 25, "bench.request": 50}),
+])
+def test_idle_labels_are_means_over_planes(monkeypatch, planes, want):
+    import jax
+
+    pd = SimpleNamespace(planes=[HOST, *planes])
+    monkeypatch.setattr(jax.profiler, "ProfileData",
+                        SimpleNamespace(from_file=lambda path: pd))
+    out = trace_reduce.reduce("synthetic", STARTS, ROOTS)
+    labels = dict(out["breakdown"]["idle_gaps"])
+    assert {k: round(v * 1e9) for k, v in labels.items()} == want
+    # busy is the mean per chip, and the labels fill the rest of the window
+    assert out["window_s"] == pytest.approx(200e-9)
+    assert out["busy_s"] == pytest.approx(100e-9)
+    assert sum(labels.values()) == pytest.approx(out["window_s"] - out["busy_s"])
+    # op seconds are summed over the chips
+    assert sum(out["op_seconds"].values()) == pytest.approx(100e-9 * len(planes))
+    if len(planes) == 1:  # one plane reads exactly what the first plane alone did
+        assert labels == _labels_of_one_plane(pd, STARTS, ROOTS)
+
+
+def test_recorded_four_chip_trace():
+    import jax
+
+    pd = jax.profiler.ProfileData.from_file(TRACE4)
+    marks = trace_reduce.anchors(pd)
+    lo, hi = marks[0][0], marks[-1][1]
+    ops = trace_reduce.device_ops(pd)
+    planes = sorted({o[3] for o in ops})
+    assert planes == [f"/device:TPU:{i}" for i in range(4)]
+    out = trace_reduce.reduce(TRACE4, [s for s, _ in marks], _roots(marks))
+    # busy is the mean over the chips of each chip's union of op intervals
+    per_chip = [trace_reduce.union_ns([(s, e) for _, s, e, p in ops if p == plane], lo, hi)
+                for plane in planes]
+    assert out["busy_s"] == pytest.approx(sum(per_chip) / 4 / 1e9)
+    idle = sum(v for _, v in out["breakdown"]["idle_gaps"])
+    assert idle == pytest.approx(out["window_s"] - out["busy_s"])
+    # op seconds are chip-seconds: at least the busy time of all four chips
+    assert sum(out["op_seconds"].values()) >= 4 * out["busy_s"] * (1 - 1e-9)
+    kinds = {trace_reduce.op_kind(n) for n, _ in out["breakdown"]["device_ops"]}
+    assert {"all-reduce", "all-gather", "sort"} <= kinds

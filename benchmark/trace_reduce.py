@@ -13,6 +13,13 @@ Read with ``jax.profiler.ProfileData`` and nothing of the program:
 idle time inside the window summed by the host span open at the time
 (the innermost program span, ``client`` between requests), each gap
 split where that span changes.
+
+Over several chips (one device plane each): ``busy_s`` and the idle
+labels are means per chip, so the labels add up to ``window_s -
+busy_s``; ``op_seconds`` and ``breakdown.device_ops`` are summed over the
+chips, chip-seconds. A roofline share that divides a call's global bytes
+by its ops' seconds summed over chips is therefore the per-chip share.
+With one plane every number is that chip's own.
 """
 
 from __future__ import annotations
@@ -203,8 +210,10 @@ def reduce(path: str, request_starts_ns=(), roots=()) -> dict | None:
         offset = float(np.median([marks[i][0] - request_starts_ns[i]
                                   for i in range(n)]))
         segments = label_segments(span_intervals(roots, offset))
-        first = sorted(by_plane)[0]
-        gaps_by = gaps_by_label(idle_gaps(by_plane[first], lo, hi), segments)
+        gaps_by = defaultdict(float)
+        for iv in by_plane.values():
+            for k, v in gaps_by_label(idle_gaps(iv, lo, hi), segments).items():
+                gaps_by[k] += v / n_planes
     out["breakdown"] = {
         "device_ops": sorted(([k, v] for k, v in op_s.items()),
                              key=lambda kv: -kv[1])[:TOP],
