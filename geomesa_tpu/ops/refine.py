@@ -31,31 +31,113 @@ _BOX_PAD = np.array([1, 0, 1, 0], dtype=np.int32)
 # for non-negative normalized coords.
 _BOX_PAD_OVERLAP = np.array([2**31 - 1, -1, 2**31 - 1, -1], dtype=np.int32)
 _TIME_PAD = np.array([1, 0, 0, -1], dtype=np.int32)
+# no spatial constraint: the whole normalized domain
+_FULL_BOX = np.array([0, 2**31 - 1, 0, 2**31 - 1], dtype=np.int32)
+# no temporal constraint: off_lo = -1 matches every row (offsets are >= 0)
+# while its endpoints are unhittable, so the exact-mode edge test never
+# flags rows of a time-unconstrained query (a real (0, 0) lo endpoint
+# would mark EVERY row of a no-dtg store as a candidate)
+_NO_TIME = np.array([0, -1, 2**31 - 1, 2**31 - 1], dtype=np.int32)
+
+
+def _pack(rows, counts, slots, pad, widen) -> np.ndarray:
+    """Each query's rows (consecutive in ``rows`` (N, 4), ``counts[q]`` of
+    them) in the first slots of its (``slots``, 4) int32 payload, ``pad``
+    after them; a query with more rows than slots gets ``widen(its rows,
+    where each such query's rows start)`` in slot 0 instead."""
+    out = np.empty((len(counts), slots, 4), dtype=np.int32)
+    out[:] = pad
+    if len(rows) == 0:
+        return out
+    q = np.repeat(np.arange(len(counts)), counts)
+    slot = np.arange(len(rows)) - (np.cumsum(counts) - counts)[q]
+    fits = counts <= slots
+    if fits.all():
+        out[q, slot] = rows
+        return out
+    put = fits[q]
+    out[q[put], slot[put]] = rows[put]
+    n = counts[~fits]
+    out[~fits, 0] = widen(rows[~put], np.cumsum(n) - n)
+    return out
+
+
+def _box_envelope(rows: np.ndarray, at: np.ndarray) -> np.ndarray:
+    return np.stack(
+        [
+            np.minimum.reduceat(rows[:, 0], at),
+            np.maximum.reduceat(rows[:, 1], at),
+            np.minimum.reduceat(rows[:, 2], at),
+            np.maximum.reduceat(rows[:, 3], at),
+        ],
+        axis=1,
+    )
+
+
+def _time_envelope(rows: np.ndarray, at: np.ndarray) -> np.ndarray:
+    # widened payloads are flagged non-exactable by the callers, so the
+    # unhittable -1 lo-offset is safe here too
+    lo = np.minimum.reduceat(rows[:, 0], at)
+    hi = np.maximum.reduceat(rows[:, 2], at)
+    return np.stack(
+        [lo, np.full_like(lo, -1), hi, np.full_like(hi, 2**31 - 1)], axis=1
+    )
+
+
+def pack_box_rows(
+    rows: np.ndarray, counts, slots: int = MAX_BOXES, overlap: bool = False
+) -> np.ndarray:
+    """The boxes of Q queries → their padded payloads (Q, ``slots``, 4)
+    int32. ``rows`` (N, 4) [xlo, xhi, ylo, yhi] ints holds every query's
+    boxes in query order, ``counts[q]`` of them for query ``q``.
+
+    A query with no boxes → the full box. More boxes than slots →
+    collapse to the bounding envelope (still a superset; residual
+    recovers exactness). ``slots`` is a compile-time shape: single-box
+    workloads pass ``slots=1`` so the device kernels skip the
+    padded-slot evaluations entirely. ``overlap=True`` pads with the
+    interval-overlap-unsatisfiable sentinel (for XZ bbox-overlap scans,
+    where the containment pad is not empty).
+    """
+    counts = np.asarray(counts, dtype=np.int64)
+    pad = _BOX_PAD_OVERLAP if overlap else _BOX_PAD
+    out = _pack(rows, counts, slots, pad, _box_envelope)
+    out[counts == 0, 0] = _FULL_BOX
+    return out
+
+
+def pack_time_rows(
+    rows: np.ndarray, counts, slots: int = MAX_TIMES, unconstrained=None
+) -> np.ndarray:
+    """The time windows of Q queries → their padded payloads (Q,
+    ``slots``, 4) int32. ``rows`` (N, 4) [bin_lo, off_lo, bin_hi, off_hi]
+    ints holds every query's windows in query order, ``counts[q]`` of
+    them for query ``q``.
+
+    ``unconstrained`` (Q,) bool, by default the queries with no windows:
+    no temporal constraint → the no-time sentinel. A query with no
+    windows that is not unconstrained matches nothing: pad in every slot.
+    More windows than slots → ``[min bin_lo, -1, max bin_hi, 2**31-1]``.
+    """
+    counts = np.asarray(counts, dtype=np.int64)
+    out = _pack(rows, counts, slots, _TIME_PAD, _time_envelope)
+    out[counts == 0 if unconstrained is None else unconstrained, 0] = _NO_TIME
+    return out
+
+
+def _one_query(payload_i32: np.ndarray | None) -> np.ndarray:
+    if payload_i32 is None:
+        return np.empty((0, 4), dtype=np.int32)
+    return np.asarray(payload_i32, dtype=np.int32).reshape(-1, 4)
 
 
 def pack_boxes(
     boxes_i32: np.ndarray | None, slots: int = MAX_BOXES, overlap: bool = False
 ) -> np.ndarray:
-    """(B, 4) [xlo, xhi, ylo, yhi] int32 → padded (``slots``, 4).
-
-    More boxes than slots → collapse to the bounding envelope (still a
-    superset; residual recovers exactness). ``slots`` is a compile-time
-    shape: single-box workloads pass ``slots=1`` so the device kernels skip
-    the padded-slot evaluations entirely. ``overlap=True`` pads with the
-    interval-overlap-unsatisfiable sentinel (for XZ bbox-overlap scans,
-    where the containment pad is not empty).
-    """
-    if boxes_i32 is None or len(boxes_i32) == 0:
-        full = np.array([[0, 2**31 - 1, 0, 2**31 - 1]], dtype=np.int32)
-        boxes_i32 = full
-    b = np.asarray(boxes_i32, dtype=np.int32)
-    if len(b) > slots:
-        b = np.array(
-            [[b[:, 0].min(), b[:, 1].max(), b[:, 2].min(), b[:, 3].max()]],
-            dtype=np.int32,
-        )
-    pad = np.broadcast_to(_BOX_PAD_OVERLAP if overlap else _BOX_PAD, (slots - len(b), 4))
-    return np.vstack([b, pad])
+    """(B, 4) [xlo, xhi, ylo, yhi] int32 → padded (``slots``, 4):
+    :func:`pack_box_rows` on one query."""
+    b = _one_query(boxes_i32)
+    return pack_box_rows(b, [len(b)], slots=slots, overlap=overlap)[0]
 
 
 def unsat_rows(box_slots: int, time_slots: int) -> tuple[np.ndarray, np.ndarray]:
@@ -72,23 +154,10 @@ def unsat_rows(box_slots: int, time_slots: int) -> tuple[np.ndarray, np.ndarray]
 
 
 def pack_times(times_i32: np.ndarray | None, slots: int = MAX_TIMES) -> np.ndarray:
-    """(T, 4) [bin_lo, off_lo, bin_hi, off_hi] int32 → padded (``slots``, 4)."""
-    if times_i32 is None or len(times_i32) == 0:
-        # unconstrained sentinel: off_lo = -1 matches every row (offsets are
-        # >= 0) while its endpoints are unhittable, so the exact-mode edge
-        # test never flags rows of a time-unconstrained query (a real (0, 0)
-        # lo endpoint would mark EVERY row of a no-dtg store as a candidate)
-        full = np.array([[0, -1, 2**31 - 1, 2**31 - 1]], dtype=np.int32)
-        times_i32 = full
-    t = np.asarray(times_i32, dtype=np.int32)
-    if len(t) > slots:
-        # widened payloads are flagged non-exactable by the callers, so the
-        # unhittable -1 lo-offset is safe here too
-        t = np.array(
-            [[t[:, 0].min(), -1, t[:, 2].max(), 2**31 - 1]], dtype=np.int32
-        )
-    pad = np.broadcast_to(_TIME_PAD, (slots - len(t), 4))
-    return np.vstack([t, pad])
+    """(T, 4) [bin_lo, off_lo, bin_hi, off_hi] int32 → padded (``slots``,
+    4): :func:`pack_time_rows` on one query (none → unconstrained)."""
+    t = _one_query(times_i32)
+    return pack_time_rows(t, [len(t)], slots=slots)[0]
 
 
 def _live_slots(payload: np.ndarray, pad: np.ndarray) -> np.ndarray:

@@ -14,8 +14,6 @@ import time
 from dataclasses import dataclass, field
 from typing import Any
 
-import numpy as np
-
 from geomesa_tpu import obs
 from geomesa_tpu.curve.binned_time import BinnedTime
 from geomesa_tpu.filter import ast
@@ -574,8 +572,8 @@ def standing_query_payload(sft: FeatureType, predicate,
     """Decompose a STANDING query (bbox + time-window predicate) into one
     subscription-matrix row: packed int-domain box and time-range payloads,
     the exact encoding the batched count kernels consume
-    (``pack_boxes``/``pack_times`` over the planner's bounds extraction —
-    the per-query analog of ``TpuBackend._payload``).
+    (``store.backends.pack_payloads`` on a batch of one, over the
+    planner's bounds extraction, at this matrix's slot counts).
 
     ``predicate`` is a CQL string, a filter AST node, or a
     :class:`Query`. Returns ``(boxes (box_slots, 4) int32, times
@@ -590,9 +588,8 @@ def standing_query_payload(sft: FeatureType, predicate,
     deliver rows the predicate rejects.
     """
     # lazy: backends imports planner — the payload helpers live there
-    from geomesa_tpu.curve.normalize import lat as norm_lat, lon as norm_lon
-    from geomesa_tpu.ops.refine import pack_boxes, pack_times, unsat_rows
-    from geomesa_tpu.store.backends import REFINE_PRECISION, time_quads
+    from geomesa_tpu.ops.refine import unsat_rows
+    from geomesa_tpu.store.backends import pack_payloads
 
     q = predicate if isinstance(predicate, Query) else Query(filter=predicate)
     f = q.resolved_filter()
@@ -603,22 +600,9 @@ def standing_query_payload(sft: FeatureType, predicate,
     e = extract(f, sft.geom_field, sft.dtg_field)
     if e.disjoint:
         return unsat_rows(box_slots, time_slots)
-    boxes_i32 = None
-    if e.boxes is not None:
-        nlon = norm_lon(REFINE_PRECISION)
-        nlat = norm_lat(REFINE_PRECISION)
-        boxes_i32 = np.array(
-            [
-                [int(nlon.normalize(x1)), int(nlon.normalize(x2)),
-                 int(nlat.normalize(y1)), int(nlat.normalize(y2))]
-                for x1, y1, x2, y2 in e.boxes
-            ],
-            dtype=np.int32,
-        )
-    return (
-        pack_boxes(boxes_i32, slots=box_slots),
-        pack_times(time_quads(sft, e.intervals), slots=time_slots),
-    )
+    boxes, times = pack_payloads(sft, [e], box_slots=box_slots,
+                                 time_slots=time_slots)
+    return boxes[0], times[0]
 
 
 # routing consults between probes of the loser — now THE shared probe

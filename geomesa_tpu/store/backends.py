@@ -147,31 +147,96 @@ def _slot_clearer(state: dict, name: str):
     return _clear
 
 
+def normalize_boxes(boxes) -> np.ndarray:
+    """(B, 4) lon/lat boxes ``(xmin, ymin, xmax, ymax)`` → (B, 4)
+    ``[xlo, xhi, ylo, yhi]`` in the device's ``REFINE_PRECISION`` int
+    domain (int64 values that fit int32). A NaN coordinate raises
+    ``ValueError``."""
+    b = np.asarray(boxes, dtype=np.float64).reshape(-1, 4)
+    return np.concatenate(
+        [
+            norm_lon(REFINE_PRECISION).normalize(b[:, 0::2]),
+            norm_lat(REFINE_PRECISION).normalize(b[:, 1::2]),
+        ],
+        axis=1,
+    )
+
+
+def _clamped_quads(binned: BinnedTime, iv: np.ndarray):
+    """(N, 2) inclusive epoch-ms intervals → (kept (N,) bool, (K, 4)
+    ``[bin_lo, off_lo, bin_hi, off_hi]`` of the kept intervals, int64
+    values that fit int32): ``lo`` clamps to 0 and ``hi`` to the last
+    indexable millisecond (one before the start of bin ``MAX_BIN + 1``);
+    an interval that clamps empty is dropped."""
+    from geomesa_tpu.curve.binned_time import MAX_BIN
+
+    hi_cap = int(binned.bin_start_millis(MAX_BIN + 1)) - 1
+    ends = iv.copy()
+    np.maximum(ends[:, 0], 0, out=ends[:, 0])
+    np.minimum(ends[:, 1], hi_cap, out=ends[:, 1])
+    kept = ends[:, 1] >= ends[:, 0]
+    # both ends of every kept interval in one conversion
+    bins, offs = binned.to_bin_and_offset(ends[kept])
+    return kept, np.stack([bins[:, 0], offs[:, 0], bins[:, 1], offs[:, 1]], axis=1)
+
+
+def _intervals(ivs) -> np.ndarray:
+    """Interval list → (N, 2) int64 (``int()``'s truncation of floats)."""
+    return np.array(ivs, dtype=np.int64).reshape(-1, 2)
+
+
 def time_quads(sft: FeatureType, intervals) -> "np.ndarray | None":
     """Interval list → (T, 4) [bin_lo, off_lo, bin_hi, off_hi] int32 quads
     (the kernels' and the GeoBlocks pyramid's shared time payload), or
     None for no temporal constraint. Every interval clamping away yields
     the unsatisfiable quad — a temporally-impossible predicate must not
     become a full-window scan."""
+    from geomesa_tpu.ops.refine import _TIME_PAD
+
     if intervals is None or not sft.dtg_field:
         return None
-    binned = BinnedTime(sft.z3_interval)
-    from geomesa_tpu.curve.binned_time import MAX_BIN
+    _, quads = _clamped_quads(BinnedTime(sft.z3_interval), _intervals(intervals))
+    if len(quads) == 0:
+        return _TIME_PAD[None].copy()
+    return quads.astype(np.int32)
 
-    quads = []
-    for lo, hi in intervals:
-        lo = max(int(lo), 0)
-        # last indexable millisecond: one before the start of bin MAX_BIN+1
-        hi_cap = int(binned.bin_start_millis(np.array([MAX_BIN + 1]))[0]) - 1
-        hi = min(int(hi), hi_cap)
-        if hi < lo:
-            continue
-        (blo,), (olo,) = binned.to_bin_and_offset(np.array([lo]))
-        (bhi,), (ohi,) = binned.to_bin_and_offset(np.array([hi]))
-        quads.append([int(blo), int(olo), int(bhi), int(ohi)])
-    if quads:
-        return np.array(quads, dtype=np.int32)
-    return np.array([[1, 0, 0, -1]], dtype=np.int32)
+
+def pack_payloads(sft: FeatureType, extractions, overlap: bool = False,
+                  box_slots: int | None = None, time_slots: int | None = None):
+    """Every query's int-domain refine payload in one pass over the whole
+    batch: ``(boxes (Q, box_slots, 4) int32, times (Q, time_slots, 4)
+    int32)``, row ``q`` the payload of ``extractions[q]``, the slots
+    ``MAX_BOXES`` / ``MAX_TIMES`` unless given.
+
+    Every query's boxes are normalized (:func:`normalize_boxes`; a NaN
+    coordinate raises ``ValueError``) and its intervals clamped and
+    binned as in :func:`time_quads`, all in one array operation each;
+    ``ops.refine.pack_box_rows`` / ``pack_time_rows`` then pad or widen
+    them (``overlap``: the XZ bbox-overlap box pad). No intervals, or no
+    date field → unconstrained; every interval clamping away → the
+    unsatisfiable time payload."""
+    from geomesa_tpu.ops.refine import (
+        MAX_BOXES,
+        MAX_TIMES,
+        pack_box_rows,
+        pack_time_rows,
+    )
+
+    nq = len(extractions)
+    nb = [0 if e.boxes is None else len(e.boxes) for e in extractions]
+    flat = [b for e in extractions if e.boxes is not None for b in e.boxes]
+    boxes = pack_box_rows(normalize_boxes(flat), nb,
+                          slots=box_slots or MAX_BOXES, overlap=overlap)
+
+    untimed = not sft.dtg_field
+    free = [untimed or e.intervals is None for e in extractions]
+    ni = [0 if f else len(e.intervals) for e, f in zip(extractions, free)]
+    flat = [v for e, n in zip(extractions, ni) if n for v in e.intervals]
+    kept, quads = _clamped_quads(BinnedTime(sft.z3_interval), _intervals(flat))
+    counts = np.bincount(np.repeat(np.arange(nq), ni)[kept], minlength=nq)
+    times = pack_time_rows(quads, counts, slots=time_slots or MAX_TIMES,
+                           unconstrained=np.array(free, dtype=bool))
+    return boxes, times
 
 
 class TpuBackend(ExecutionBackend):
@@ -476,26 +541,8 @@ class TpuBackend(ExecutionBackend):
 
     # -- refine payload (int-domain superset bounds) -------------------------
     def _payload(self, sft: FeatureType, e: Extraction, overlap: bool = False):
-        from geomesa_tpu.ops.refine import pack_boxes, pack_times
-
-        nlon = norm_lon(REFINE_PRECISION)
-        nlat = norm_lat(REFINE_PRECISION)
-        boxes = None
-        if e.boxes is not None:
-            boxes = np.array(
-                [
-                    [
-                        int(nlon.normalize(x1)),
-                        int(nlon.normalize(x2)),
-                        int(nlat.normalize(y1)),
-                        int(nlat.normalize(y2)),
-                    ]
-                    for x1, y1, x2, y2 in e.boxes
-                ],
-                dtype=np.int32,
-            )
-        times = time_quads(sft, e.intervals)
-        return pack_boxes(boxes, overlap=overlap), pack_times(times)
+        boxes, times = pack_payloads(sft, [e], overlap=overlap)
+        return boxes[0], times[0]
 
     @dispatch_budget(2, signatures=("*:rows",))
     def select(self, state, index, plan, extraction, residual, table):
@@ -625,10 +672,7 @@ class TpuBackend(ExecutionBackend):
             budget = pad_bucket(len(pair_q), minimum=chunk)
             pq, pb = pad_block_pairs(pair_q, pair_blk, budget)
             overlap = dev.kind == "bboxes"
-            payloads = [
-                self._payload(index.sft, e, overlap=overlap)
-                for e in extractions
-            ]
+            boxes, times = pack_payloads(index.sft, extractions, overlap=overlap)
             # bucket the query-batch dimension too: every compile-time
             # shape (nqp, budget, capacity) is a bucket, so naturally
             # varying batch sizes reuse cached executables instead of
@@ -642,14 +686,8 @@ class TpuBackend(ExecutionBackend):
             from geomesa_tpu.parallel.mesh import QUERY_AXIS
 
             nqp = math.lcm(pad_bucket(nq, minimum=4), mesh.shape[QUERY_AXIS])
-            boxes = np.stack(
-                [p[0] for p in payloads]
-                + [np.zeros_like(payloads[0][0])] * (nqp - nq)
-            )
-            times = np.stack(
-                [p[1] for p in payloads]
-                + [np.zeros_like(payloads[0][1])] * (nqp - nq)
-            )
+            boxes = np.pad(boxes, ((0, nqp - nq), (0, 0), (0, 0)))
+            times = np.pad(times, ((0, nqp - nq), (0, 0), (0, 0)))
             from geomesa_tpu.obs.jaxmon import count_h2d
 
             count_h2d(pq, pb, boxes, times)  # per-batch payload staging

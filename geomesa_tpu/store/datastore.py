@@ -1736,19 +1736,23 @@ class DataStore:
         residual semantics the loose kernels can't honor) → their int-domain
         payloads. ``viewport``: intersect every query's spatial bounds with
         this (xmin, ymin, xmax, ymax) box — rows outside it must not match
-        (the density viewport). Returns [(query idx, payload | None,
-        exactable)] — ``exactable`` is False when packing WIDENED the
-        payload (more boxes/intervals than the kernel slots), i.e. the int
-        result is a superset even beyond edge-bucket quantization and
-        cannot be corrected to exact."""
+        (the density viewport). The payloads of the whole batch are packed
+        by one :func:`~geomesa_tpu.store.backends.pack_payloads` call.
+        Returns [(query idx, payload | None, exactable)], each payload a
+        (boxes, times) pair of rows of that call's arrays; ``exactable``
+        is False when packing WIDENED the payload (more boxes/intervals
+        than the kernel slots), i.e. the int result is a superset even
+        beyond edge-bucket quantization and cannot be corrected to
+        exact."""
         from dataclasses import replace as _replace
 
-        from geomesa_tpu.filter.bounds import extract as _extract
+        from geomesa_tpu.filter.bounds import Extraction, extract as _extract
         from geomesa_tpu.ops.refine import MAX_BOXES, MAX_TIMES
+        from geomesa_tpu.store.backends import pack_payloads
 
-        pending: list[tuple[int, tuple | None, bool]] = []
-        # the batch's planning: every query's parse, extraction and
-        # payload, under ONE span for the whole batch
+        pending: list[tuple[int, Extraction | None, bool]] = []
+        # the batch's planning: every query's parse and extraction, then
+        # one payload pass over all of them, under ONE span for the batch
         with obs.span("plan", queries=len(qs)):
             for i, q in enumerate(qs):
                 f = q.resolved_filter()
@@ -1776,16 +1780,19 @@ class DataStore:
                         pending.append((i, None, True))
                         continue
                     e = _replace(e, boxes=clipped)
-                payload = (
-                    None
-                    if e.disjoint
-                    else self.backend._payload(st.sft, e, overlap=overlap)
-                )
                 n_boxes = len(e.boxes) if e.boxes is not None else 1
                 n_times = len(e.intervals) if e.intervals is not None else 1
                 exactable = n_boxes <= MAX_BOXES and n_times <= MAX_TIMES
-                pending.append((i, payload, exactable))
-        return pending
+                pending.append((i, None if e.disjoint else e, exactable))
+            packed = [e for _, e, _ in pending if e is not None]
+            boxes, times = pack_payloads(st.sft, packed, overlap=overlap)
+        self.metrics.counter("store.batch_plan.queries").inc(len(qs))
+        self.metrics.counter("store.batch_plan.packed").inc(len(packed))
+        rows = zip(boxes, times)
+        return [
+            (i, None if e is None else next(rows), ok)
+            for i, e, ok in pending
+        ]
 
     def _stage_batch(self, live, *extra):
         """Host staging of a batched fan-out before its one dispatch: the
@@ -2545,16 +2552,11 @@ class DataStore:
         + boundary rows refined against the full f64 filter AST + the
         delta fold — the same correction machinery the fused device path
         feeds (:meth:`_assemble_agg`)."""
-        from geomesa_tpu.curve.normalize import lat as norm_lat, lon as norm_lon
-        from geomesa_tpu.store.backends import REFINE_PRECISION, time_quads
+        from geomesa_tpu.store.backends import normalize_boxes, time_quads
 
         box = None
         if e.boxes is not None:
-            nlon = norm_lon(REFINE_PRECISION)
-            nlat = norm_lat(REFINE_PRECISION)
-            x1, y1, x2, y2 = e.boxes[0]
-            box = (int(nlon.normalize(x1)), int(nlon.normalize(x2)),
-                   int(nlat.normalize(y1)), int(nlat.normalize(y2)))
+            box = tuple(int(v) for v in normalize_boxes(e.boxes[:1])[0])
         window = None
         quads = time_quads(st.sft, e.intervals)
         if quads is not None:
