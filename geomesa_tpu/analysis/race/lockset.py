@@ -184,7 +184,7 @@ class _Project:
         self.functions: dict[str, dict[str, ast.FunctionDef]] = {}
         # (module_id, fn) -> class its return annotation resolves to —
         # lets cross-module scans type ``devmon.costs().forget(...)``
-        # through the ``def costs() -> CostTable`` accessor idiom
+        # through the ``def costs() -> PlanTable`` accessor idiom
         self.fn_returns: dict[tuple[str, str], str] = {}
 
         for mod in modules:
@@ -432,7 +432,7 @@ class _FnScan(ast.NodeVisitor):
                     return self.p.classes[recv].method_returns.get(f.attr)
             if self.cross_module:
                 # module-level accessor returns: ``devmon.costs()`` types
-                # as CostTable through ``def costs() -> CostTable``
+                # as PlanTable through ``def costs() -> PlanTable``
                 key = self.p.local_fn_key(self.imports.resolve(f))
                 if key is None and isinstance(f, ast.Name):
                     if f.id in self.p.functions.get(self.mid, {}):

@@ -44,7 +44,7 @@ stack already observes latency, devices and tenants:
 
 Hygiene: every execution the auditor itself triggers (referee scans are
 pure host code; the minimizer ALSO re-runs the live path) runs inside
-:func:`shadow`, and the store's feedback planes — CostTable
+:func:`shadow`, and the store's feedback planes — plan-table
 observations, usage metering, SLO burn, workload capture — all consult
 :func:`in_shadow` and skip shadow traffic (the same rule ISSUE 11's
 replay applies to capture). The off path costs one module-global bool

@@ -1,5 +1,5 @@
 """Device telemetry: HBM residency ledger, per-query device-time
-attribution, and the online cost-profile table.
+attribution, and the cost-profile accessor and sidecar.
 
 The rest of the obs stack sees the *host* pipeline end to end; this module
 opens the device black box with three substrates (docs/observability.md
@@ -26,21 +26,20 @@ opens the device black box with three substrates (docs/observability.md
   bound on the cached-jit select path is asserted in
   ``tests/test_devmon.py`` and gated in ``scripts/lint.sh``).
 
-- :class:`CostTable` — attribution records aggregate into an online
-  per-(type, plan-signature) cost profile (p50/p95 device-ms and wall-ms,
-  bytes scanned, rows returned), served at ``GET /api/obs/costs`` and
-  rendered by ``explain(analyze=True)`` as predicted-vs-actual. Read-only
-  for now: it is exactly the observed-cost table the adaptive planner
-  (ROADMAP item 3) will consume.
+- :func:`costs` — the online per-(type, plan-signature) cost profiles
+  (p50/p95 device-ms and wall-ms, bytes scanned, rows returned) kept in
+  the plan table (:mod:`geomesa_tpu.obs.plantable`), served at
+  ``GET /api/obs/costs``, rendered by ``explain(analyze=True)`` as
+  predicted-vs-actual, consumed by the adaptive planner, and persisted
+  to the ``costs.json`` sidecar (:func:`save_cost_snapshot`).
 
-Locking: the ledger and cost table each own one leaf lock (same tier as
-the metrics-registry locks — docs/concurrency.md); no blocking calls run
-under either. No jax at module level (``GEOMESA_TPU_NO_JAX=1`` safe).
+Locking: the ledger owns one leaf lock (same tier as the metrics-registry
+locks — docs/concurrency.md); no blocking calls run under it. No jax at
+module level (``GEOMESA_TPU_NO_JAX=1`` safe).
 """
 
 from __future__ import annotations
 
-import math
 import os
 import random
 import threading
@@ -48,10 +47,12 @@ import weakref
 from contextlib import contextmanager
 from contextvars import ContextVar
 
-from geomesa_tpu.analysis.contracts import cache_surface, feedback_sink
+from geomesa_tpu.analysis.contracts import cache_surface
+from geomesa_tpu.obs import plantable
+from geomesa_tpu.obs.plantable import PlanTable
 
 __all__ = [
-    "DEVPROF_ENV", "CostTable", "DevProfile", "ResidencyLedger",
+    "DEVPROF_ENV", "DevProfile", "ResidencyLedger",
     "cost_sidecar_path", "costs", "current_profile", "device_report",
     "install", "ledger", "load_cost_snapshot", "plan_signature",
     "profiled", "prometheus_text", "purge_persisted_costs",
@@ -448,288 +449,26 @@ def plan_signature(info, q=None) -> str:
     return ":".join(parts)
 
 
-# -- online cost profiles -----------------------------------------------------
-
-class _Quantiles:
-    """Bounded reservoir (algorithm R) + count/sum — the same shape as
-    :class:`geomesa_tpu.utils.metrics.Histogram` without the import (this
-    module stays dependency-free for ``GEOMESA_TPU_NO_JAX`` processes).
-    NOT thread-safe on its own: the owning :class:`CostTable` lock guards
-    every update/read."""
-
-    __slots__ = ("count", "total", "_res", "_rng", "_qcache")
-    SIZE = 256
-    # above this many observations, quantiles serve from a cache refreshed
-    # every CACHE_DELTA updates — predict() rides the per-query audit hot
-    # path (the adaptive planner consults it every dispatch) and a fresh
-    # reservoir sort per call would erode the <2% overhead bound. Below
-    # the threshold quantiles stay exact (small-sample tests pin values).
-    CACHE_MIN = 64
-    CACHE_DELTA = 16
-
-    def __init__(self):
-        self.count = 0
-        self.total = 0.0
-        self._res: list[float] = []
-        self._rng = random.Random(0x5DEECE66D)
-        self._qcache: dict[float, tuple[int, float]] = {}
-
-    def to_state(self) -> dict:
-        """JSON-able state (cost-profile persistence — the reservoir IS
-        the learned distribution; the RNG restarts, which only changes
-        which FUTURE samples replace which slots)."""
-        return {"count": self.count, "total": self.total,
-                "res": [round(v, 4) for v in self._res]}
-
-    def load_state(self, state: dict) -> None:
-        self.count = int(state.get("count", 0))
-        self.total = float(state.get("total", 0.0))
-        self._res = [float(v) for v in state.get("res", [])][:self.SIZE]
-        self._qcache = {}
-
-    def update(self, v: float) -> None:
-        self.count += 1
-        self.total += v
-        if len(self._res) < self.SIZE:
-            self._res.append(v)
-        else:
-            j = self._rng.randrange(self.count)
-            if j < self.SIZE:
-                self._res[j] = v
-
-    def quantile(self, q: float) -> float:
-        if not self._res:
-            return 0.0
-        hit = self._qcache.get(q)
-        if (hit is not None and self.count > self.CACHE_MIN
-                and self.count - hit[0] < self.CACHE_DELTA):
-            return hit[1]
-        s = sorted(self._res)
-        pos = q * (len(s) - 1)
-        lo = int(pos)
-        hi = min(lo + 1, len(s) - 1)
-        frac = pos - lo
-        v = s[lo] * (1.0 - frac) + s[hi] * frac
-        self._qcache[q] = (self.count, v)
-        return v
-
-
-class _CostEntry:
-    __slots__ = ("wall_ms", "device_ms", "rows", "bytes_scanned", "count",
-                 "profiled_count")
-
-    def __init__(self):
-        self.wall_ms = _Quantiles()
-        self.device_ms = _Quantiles()
-        self.rows = _Quantiles()
-        self.bytes_scanned = _Quantiles()
-        self.count = 0
-        self.profiled_count = 0
-
-
-@cache_surface(name="device-cost-table", keyed_by="type_name",
-               purge=("forget",))
-class CostTable:
-    """Online per-(type, plan-signature) observed-cost aggregation.
-
-    Every completed query observes wall-ms / rows / bytes-scanned; queries
-    that ran under :func:`profiled` additionally observe device-ms. Read
-    surfaces: :meth:`snapshot` (``GET /api/obs/costs``) and
-    :meth:`predict` (``explain`` predicted-vs-actual). Bounded: least-
-    recently-observed signatures evict past ``max_entries``."""
-
-    def __init__(self, max_entries: int = 512):
-        from collections import OrderedDict
-
-        self._lock = threading.Lock()  # leaf: the entry table
-        self._entries: "OrderedDict[tuple, _CostEntry]" = OrderedDict()
-        self._ticks: dict[tuple, int] = {}
-        self.max_entries = max_entries
-
-    def tick(self, type_name: str, name: str) -> int:
-        """Monotonic per-(type, name) consult counter. Routing policies
-        (``planner.choose_agg_path``) schedule periodic probes of the
-        losing route off this — NOT off observation counts, which the
-        winning route freezes by starving the loser of observations."""
-        key = (type_name, name)
-        with self._lock:
-            n = self._ticks.get(key, 0) + 1
-            self._ticks[key] = n
-        return n
-
-    def forget(self, type_name: str) -> None:
-        """Drop every signature row and consult tick of one type. A
-        deleted or renamed schema must not hand its observed cost profile
-        (or its probe phase) to an unrelated future type of the same
-        name."""
-        with self._lock:
-            for k in [k for k in self._entries if k[0] == type_name]:
-                del self._entries[k]
-            for k in [k for k in self._ticks if k[0] == type_name]:
-                del self._ticks[k]
-
-    @feedback_sink
-    def observe(self, type_name: str, signature: str, *,
-                wall_ms: float, device_ms: float | None = None,
-                rows: int = 0, bytes_scanned: int = 0) -> None:
-        if not _finite(wall_ms):
-            return  # a clock anomaly must never poison a reservoir
-        key = (type_name, signature)
-        with self._lock:
-            e = self._entries.get(key)
-            if e is None:
-                e = self._entries[key] = _CostEntry()
-                while len(self._entries) > self.max_entries:
-                    self._entries.popitem(last=False)
-            else:
-                self._entries.move_to_end(key)
-            e.count += 1
-            e.wall_ms.update(float(wall_ms))
-            e.rows.update(float(rows))
-            if bytes_scanned:
-                e.bytes_scanned.update(float(bytes_scanned))
-            if device_ms is not None:
-                e.profiled_count += 1
-                e.device_ms.update(float(device_ms))
-
-    def predict(self, type_name: str, signature: str) -> dict | None:
-        """The table's current p50 cost for one plan shape (None when the
-        shape has never been observed) — what ``explain`` shows as
-        *predicted* and the adaptive planner will rank strategies by."""
-        with self._lock:
-            e = self._entries.get((type_name, signature))
-            if e is None:
-                return None
-            return {
-                "wall_ms_p50": round(e.wall_ms.quantile(0.5), 3),
-                "wall_ms_p95": round(e.wall_ms.quantile(0.95), 3),
-                "device_ms_p50": (
-                    round(e.device_ms.quantile(0.5), 3)
-                    if e.profiled_count else None
-                ),
-                "observations": e.count,
-            }
-
-    def predict_prefix(self, type_name: str, prefix: str) -> dict | None:
-        """Aggregated profile over every signature of one type starting
-        with ``prefix`` — how the adaptive planner reads STRATEGY-level
-        costs (audit signatures are ``index:ivN:agg``; the strategy
-        decision keys by ``index:`` alone). Observation-weighted means of
-        the per-signature p50/p95 (a strategy's profile is dominated by
-        the shapes it actually serves); None when nothing matches."""
-        with self._lock:
-            matched = [
-                e for (t, sig), e in self._entries.items()
-                if t == type_name and sig.startswith(prefix)
-            ]
-            if not matched:
-                return None
-            n = sum(e.count for e in matched)
-            p50 = sum(e.wall_ms.quantile(0.5) * e.count for e in matched) / n
-            p95 = sum(e.wall_ms.quantile(0.95) * e.count for e in matched) / n
-            return {
-                "wall_ms_p50": round(p50, 3),
-                "wall_ms_p95": round(p95, 3),
-                "device_ms_p50": None,
-                "observations": n,
-                "signatures": len(matched),
-            }
-
-    # -- persistence (docs/observability.md § Cost-model persistence) ---------
-    def to_state(self) -> dict:
-        """The table's full learned state as JSON-able data: per-(type,
-        signature) reservoirs + counts, plus the consult ticks (probe
-        cadence must survive a restart too, or every reopened store
-        re-probes from scratch)."""
-        with self._lock:
-            entries = []
-            for (t, sig), e in self._entries.items():
-                entries.append({
-                    "type": t, "signature": sig, "count": e.count,
-                    "profiled_count": e.profiled_count,
-                    "wall_ms": e.wall_ms.to_state(),
-                    "device_ms": e.device_ms.to_state(),
-                    "rows": e.rows.to_state(),
-                    "bytes_scanned": e.bytes_scanned.to_state(),
-                })
-            ticks = [[t, n, v] for (t, n), v in self._ticks.items()]
-        return {"entries": entries, "ticks": ticks}
-
-    def load_state(self, state: dict) -> None:
-        """Restore a :meth:`to_state` snapshot. Merge by richness: a
-        snapshot row only lands when it has MORE observations than the
-        live same-key entry — loading at store open must not wipe (or
-        regress) profiles another open store already learned past the
-        snapshot; unrelated live rows are never touched."""
-        for row in state.get("entries", []):
-            key = (row["type"], row["signature"])
-            e = _CostEntry()
-            e.count = int(row.get("count", 0))
-            e.profiled_count = int(row.get("profiled_count", 0))
-            e.wall_ms.load_state(row.get("wall_ms", {}))
-            e.device_ms.load_state(row.get("device_ms", {}))
-            e.rows.load_state(row.get("rows", {}))
-            e.bytes_scanned.load_state(row.get("bytes_scanned", {}))
-            with self._lock:
-                live = self._entries.get(key)
-                if live is not None and live.count >= e.count:
-                    continue
-                self._entries[key] = e
-                self._entries.move_to_end(key)
-                while len(self._entries) > self.max_entries:
-                    self._entries.popitem(last=False)
-        for t, n, v in state.get("ticks", []):
-            with self._lock:
-                key = (t, n)
-                self._ticks[key] = max(self._ticks.get(key, 0), int(v))
-
-    def snapshot(self, limit: int = 256) -> dict:
-        with self._lock:
-            items = list(self._entries.items())[-limit:]
-            rows = []
-            for (t, sig), e in items:
-                rows.append({
-                    "type": t,
-                    "signature": sig,
-                    "count": e.count,
-                    "profiled": e.profiled_count,
-                    "wall_ms_p50": round(e.wall_ms.quantile(0.5), 3),
-                    "wall_ms_p95": round(e.wall_ms.quantile(0.95), 3),
-                    "device_ms_p50": round(e.device_ms.quantile(0.5), 3),
-                    "device_ms_p95": round(e.device_ms.quantile(0.95), 3),
-                    "rows_p50": round(e.rows.quantile(0.5), 1),
-                    "bytes_scanned_p50": round(
-                        e.bytes_scanned.quantile(0.5), 0),
-                })
-        rows.sort(key=lambda r: (r["type"], r["signature"]))
-        return {"entries": rows, "entry_count": len(rows)}
-
-
 # -- process-wide singletons --------------------------------------------------
 
 _ledger = ResidencyLedger()
-_costs = CostTable()
 
 
 def ledger() -> ResidencyLedger:
     return _ledger
 
 
-def costs() -> CostTable:
-    return _costs
+def costs() -> PlanTable:
+    """The cost profiles: the process-wide plan table."""
+    return plantable.table()
 
 
-def install(new_ledger: ResidencyLedger | None = None,
-            new_costs: CostTable | None = None) -> tuple:
-    """Swap the process singletons (test isolation); returns the previous
-    (ledger, costs) pair. Entries registered against the OLD ledger keep
+def install(new_ledger: ResidencyLedger) -> ResidencyLedger:
+    """Swap the process residency ledger (test isolation); returns the
+    previous one. Entries registered against the OLD ledger keep
     unregistering against it — their finalizers captured the instance."""
-    global _ledger, _costs
-    prev = (_ledger, _costs)
-    if new_ledger is not None:
-        _ledger = new_ledger
-    if new_costs is not None:
-        _costs = new_costs
+    global _ledger
+    prev, _ledger = _ledger, new_ledger
     return prev
 
 
@@ -758,8 +497,8 @@ def prometheus_text(prefix: str = "geomesa") -> str:
 
 
 # -- cost-profile persistence (the GEOMESA_TPU_WORKLOAD_DIR sidecar) ----------
-# Learned p50 rankings and calibration survive restarts: the cost table
-# (+ the cost model's calibration entries) snapshot to costs.json next to
+# Learned p50 rankings and calibration survive restarts: the plan table's
+# cost profiles and calibration entries snapshot to costs.json next to
 # the workload capture, loaded at store open (store.persistence.load) and
 # saved at catalog save. Schema delete/rename purges the persisted rows
 # along with the live ones (DataStore._purge_type_name).
@@ -779,7 +518,7 @@ def cost_sidecar_path(path: str | None = None) -> "str | None":
 @cache_surface(name="persisted-cost-sidecar", keyed_by="type_name",
                purge=("purge_persisted_costs",))
 def save_cost_snapshot(path: str | None = None) -> "str | None":
-    """Persist the live cost table + calibration state; returns the path
+    """Persist the live cost profiles + calibration state; returns the path
     written (None when no sidecar location is configured). Atomic
     (tmp + replace): a crash mid-save must not truncate the previous
     snapshot."""
@@ -788,12 +527,11 @@ def save_cost_snapshot(path: str | None = None) -> "str | None":
     p = cost_sidecar_path(path)
     if p is None:
         return None
-    from geomesa_tpu.planning import costmodel
-
+    tbl = plantable.table()
     doc = {
         "kind": "geomesa-cost-snapshot",
-        "costs": _costs.to_state(),
-        "calibration": costmodel.model().calibration_state(),
+        "costs": tbl.to_state(),
+        "calibration": tbl.calibration_state(),
     }
     os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
     tmp = p + ".tmp"
@@ -804,7 +542,7 @@ def save_cost_snapshot(path: str | None = None) -> "str | None":
 
 
 def load_cost_snapshot(path: str | None = None) -> bool:
-    """Load a persisted snapshot into the live table + cost model (no-op
+    """Load a persisted snapshot into the live plan table (no-op
     when the sidecar is absent/unreadable — a missing or corrupt snapshot
     must never fail a store open). Returns True when state loaded."""
     import json
@@ -819,10 +557,9 @@ def load_cost_snapshot(path: str | None = None) -> bool:
         return False
     if doc.get("kind") != "geomesa-cost-snapshot":
         return False
-    _costs.load_state(doc.get("costs", {}))
-    from geomesa_tpu.planning import costmodel
-
-    costmodel.model().load_calibration_state(doc.get("calibration", {}))
+    tbl = plantable.table()
+    tbl.load_state(doc.get("costs", {}))
+    tbl.load_calibration_state(doc.get("calibration", {}))
     return True
 
 
@@ -858,9 +595,3 @@ def purge_persisted_costs(type_name: str, path: str | None = None) -> None:
         os.replace(tmp, p)
     except (OSError, ValueError):
         return
-
-
-# math import kept honest: _Quantiles interpolation uses pure arithmetic,
-# but a NaN wall-ms (a clock anomaly) must never poison a reservoir
-def _finite(v: float) -> bool:
-    return isinstance(v, (int, float)) and math.isfinite(v)

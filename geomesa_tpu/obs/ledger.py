@@ -15,8 +15,10 @@ This module is that evidence plane:
   ``QueryLedger.note_sync``. Between consecutive device activities the
   ledger derives the INTER-STAGE HOST GAP: wall time where the device sat
   idle while host code choreographed the next dispatch.
-- :class:`LedgerTable` — the bounded per-(type, plan-signature) rollup.
-  ``fusion_report()`` ranks signatures by host-choreography share
+- the per-(type, plan-signature) rollup lives in the plan table
+  (:mod:`geomesa_tpu.obs.plantable`, :func:`table`), which ``_audit``
+  charges with each query's ledger. Its ``fusion_report()`` ranks
+  signatures by host-choreography share
   ``(host_gap_ms + sync_ms) / wall_ms`` — the work list for whole-plan
   compilation, served at ``GET /api/obs/fusion`` and
   ``geomesa-tpu obs fusion-report``.
@@ -32,8 +34,8 @@ audit shadow traffic) costs one ContextVar read per dispatch. The on path
 adds one leaf-lock acquisition per dispatch/sync against device calls that
 cost milliseconds. No jax anywhere (``GEOMESA_TPU_NO_JAX=1`` safe).
 
-Locking: ``QueryLedger`` and ``LedgerTable`` each own one leaf lock
-(metrics tier, docs/concurrency.md); nothing blocking runs under either.
+Locking: ``QueryLedger`` owns one leaf lock (metrics tier,
+docs/concurrency.md); nothing blocking runs under it.
 """
 
 from __future__ import annotations
@@ -43,12 +45,11 @@ import time
 from contextlib import contextmanager
 from contextvars import ContextVar
 
-from geomesa_tpu.analysis.contracts import cache_surface, feedback_sink
 from geomesa_tpu.obs import trace as _trace
 
 __all__ = [
-    "QueryLedger", "LedgerTable", "roundtrip", "current", "note_dispatch",
-    "materialize", "table", "install",
+    "QueryLedger", "roundtrip", "current", "note_dispatch",
+    "materialize", "table",
     "EXPORT_KIND", "EXPORT_SCHEMA_VERSION",
 ]
 
@@ -62,11 +63,6 @@ EXPORT_SCHEMA_VERSION = 1
 
 _led_var: ContextVar[QueryLedger | None] = ContextVar(
     "geomesa_roundtrip_ledger", default=None)
-
-# rollup-table cardinality cap: (type, signature) keys are bounded in
-# practice (few types x few plan shapes), the cap is a safety valve against
-# a pathological filter stream minting unbounded signatures
-_MAX_ENTRIES = 256
 
 
 class QueryLedger:
@@ -184,149 +180,10 @@ def materialize(obj):
     return out
 
 
-class _Rollup:
-    """One (type, plan-signature) rollup row."""
+def table():
+    """The process-wide rollup: the plan table
+    (:func:`geomesa_tpu.obs.plantable.table`), whose ``export()`` and
+    ``fusion_report()`` read the charged rollup sums."""
+    from geomesa_tpu.obs import plantable
 
-    __slots__ = ("queries", "dispatches", "compiles", "dispatch_ms", "syncs",
-                 "sync_ms", "host_gap_ms", "wall_ms", "h2d_bytes",
-                 "d2h_bytes")
-
-    def __init__(self) -> None:
-        self.queries = 0
-        self.dispatches = 0
-        self.compiles = 0
-        self.dispatch_ms = 0.0
-        self.syncs = 0
-        self.sync_ms = 0.0
-        self.host_gap_ms = 0.0
-        self.wall_ms = 0.0
-        self.h2d_bytes = 0
-        self.d2h_bytes = 0
-
-
-@cache_surface(name="roundtrip-ledger", keyed_by="type_name",
-               purge=("forget",))
-class LedgerTable:
-    """Bounded per-(type, plan-signature) roundtrip rollup. Entries for a
-    dropped/renamed type are purged via :meth:`forget` alongside the cost
-    table (``DataStore._purge_type_name``) — stale signatures must not
-    keep ranking in the fusion report after their schema is gone."""
-
-    def __init__(self, max_entries: int = _MAX_ENTRIES):
-        self._lock = threading.Lock()  # leaf: rollup table
-        self._max = max_entries
-        self._rows: dict[tuple[str, str], _Rollup] = {}
-
-    @feedback_sink
-    def charge(self, type_name: str, signature: str, ql: QueryLedger,
-               wall_ms: float) -> None:
-        """Fold one query's ledger into the (type, signature) rollup. A
-        coalesced batch charges the SHARED ledger once per member query —
-        every signature served by the batched dispatch sees its counts."""
-        snap = ql.snapshot()
-        key = (type_name, signature)
-        with self._lock:
-            row = self._rows.get(key)
-            if row is None:
-                if len(self._rows) >= self._max:
-                    # drop the coldest row (fewest queries) — cardinality
-                    # valve, not an accuracy surface
-                    coldest = min(self._rows, key=lambda k: self._rows[k].queries)
-                    del self._rows[coldest]
-                row = self._rows[key] = _Rollup()
-            row.queries += 1
-            row.dispatches += snap["dispatches"]
-            row.compiles += snap["compiles"]
-            row.dispatch_ms += snap["dispatch_ms"]
-            row.syncs += snap["syncs"]
-            row.sync_ms += snap["sync_ms"]
-            row.host_gap_ms += snap["host_gap_ms"]
-            row.wall_ms += max(wall_ms, 0.0)
-            row.h2d_bytes += snap["h2d_bytes"]
-            row.d2h_bytes += snap["d2h_bytes"]
-
-    def forget(self, type_name: str) -> None:
-        """Purge every rollup row for ``type_name`` (schema delete/rename)."""
-        with self._lock:
-            for key in [k for k in self._rows if k[0] == type_name]:
-                del self._rows[key]
-
-    def fusion_report(self, limit: int = 50) -> list[dict]:
-        """Plan signatures ranked by host-choreography share — the fraction
-        of wall time spent in inter-stage host gaps plus sync waits. High
-        share + multiple dispatches per query = a fusion opportunity
-        (ROADMAP item 1 work list)."""
-        with self._lock:
-            items = list(self._rows.items())
-        out = []
-        for (type_name, sig), row in items:
-            if row.queries == 0:
-                continue
-            wall = max(row.wall_ms, row.dispatch_ms + row.sync_ms
-                       + row.host_gap_ms, 1e-9)
-            share = min(1.0, (row.host_gap_ms + row.sync_ms) / wall)
-            out.append({
-                "type": type_name,
-                "signature": sig,
-                "queries": row.queries,
-                "dispatches_per_query": row.dispatches / row.queries,
-                "syncs_per_query": row.syncs / row.queries,
-                "compiles": row.compiles,
-                "host_gap_ms": round(row.host_gap_ms, 3),
-                "sync_ms": round(row.sync_ms, 3),
-                "dispatch_ms": round(row.dispatch_ms, 3),
-                "wall_ms": round(row.wall_ms, 3),
-                "host_share": round(share, 4),
-                "h2d_bytes": row.h2d_bytes,
-                "d2h_bytes": row.d2h_bytes,
-            })
-        out.sort(key=lambda r: (-r["host_share"], -r["wall_ms"]))
-        return out[:limit]
-
-    def snapshot(self) -> dict:
-        return {"entries": self.fusion_report(limit=_MAX_ENTRIES)}
-
-    def export(self) -> dict:
-        """The stable reconcile-export document (``obs ledger-export``,
-        ``GET /api/obs/ledger?format=json``): one entry per (type, plan
-        signature) with the raw rollup counters. Consumers key off
-        ``kind`` + ``schema_version`` and must reject anything else."""
-        with self._lock:
-            items = sorted(self._rows.items())
-        return {
-            "kind": EXPORT_KIND,
-            "schema_version": EXPORT_SCHEMA_VERSION,
-            "entries": [
-                {
-                    "type": type_name,
-                    "signature": sig,
-                    "queries": row.queries,
-                    "dispatches": row.dispatches,
-                    "compiles": row.compiles,
-                    "syncs": row.syncs,
-                    "dispatch_ms": round(row.dispatch_ms, 3),
-                    "sync_ms": round(row.sync_ms, 3),
-                    "host_gap_ms": round(row.host_gap_ms, 3),
-                    "wall_ms": round(row.wall_ms, 3),
-                    "h2d_bytes": row.h2d_bytes,
-                    "d2h_bytes": row.d2h_bytes,
-                }
-                for (type_name, sig), row in items
-            ],
-        }
-
-
-_table = LedgerTable()
-
-
-def table() -> LedgerTable:
-    """The process-wide rollup table."""
-    return _table
-
-
-def install(tbl: LedgerTable) -> LedgerTable:
-    """Swap the process-wide table (tests); returns the previous one."""
-    global _table
-    prev = _table
-    _table = tbl
-    return prev
+    return plantable.table()

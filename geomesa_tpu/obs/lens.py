@@ -1,39 +1,33 @@
-"""query-lens: retained per-(type, plan-signature) latency history with
-trace exemplars, plus the live regression sentinel.
+"""query-lens: the shared latency histogram ring, its Prometheus
+exposition, and the live regression sentinel.
 
-The existing observability planes are point-in-time (Prometheus snapshot,
-cost-table means) or per-event (flight ring): none can answer "since
-when is signature X slow, and show me one slow trace". This module is the
-retained plane:
-
-- :class:`LatencyLens` — per (feature type, plan signature) series, each a
-  bounded ring of TIME-BUCKETED latency histograms (fixed log-scale
-  ``le`` bin edges, 10 s buckets, 1 h retained by default — the same
-  bucketed-deque shape as the SLO engine's burn-rate counters). Each
-  bucket also accumulates rows/dispatches and keeps up to
-  ``EXEMPLARS_PER_BUCKET`` *trace exemplars*: the (latency, trace_id)
-  pairs of the bucket's slowest traced queries — so the tail (p99+) of
-  every bucket is one lookup away from its stitched federated span tree
-  (``trace.recent()`` → flight dumps). Served at ``GET /api/obs/lens``
-  and ``geomesa-tpu obs lens``.
-- Prometheus exposition: :meth:`LatencyLens.prometheus_lines` emits TRUE
-  histogram families — ``geomesa_lens_latency_ms_bucket`` with cumulative
-  ``le`` labels plus ``_sum``/``_count`` under ``# TYPE ... histogram``
-  (the summary-style quantile emission in :mod:`obs.export` cannot be
-  aggregated across instances; these can).
+- :class:`HistogramRing` — per-key series, each a bounded ring of
+  TIME-BUCKETED latency histograms (fixed log-scale ``le`` bin edges,
+  10 s buckets, 1 h retained by default — the same bucketed-deque shape
+  as the SLO engine's burn-rate counters). Each bucket also accumulates
+  rows/dispatches and keeps up to ``EXEMPLARS_PER_BUCKET`` *trace
+  exemplars*: the (latency, trace_id) pairs of the bucket's slowest
+  traced queries — so the tail (p99+) of every bucket is one lookup away
+  from its stitched federated span tree (``trace.recent()`` → flight
+  dumps). Two subclasses: the plan table
+  (:class:`geomesa_tpu.obs.plantable.PlanTable`, one record per (type,
+  plan signature), served at ``GET /api/obs/lens`` and ``geomesa-tpu obs
+  lens``) and the stream delivery lens (:mod:`geomesa_tpu.obs.streamlens`).
+- :func:`histogram_lines` — TRUE Prometheus histogram families —
+  ``geomesa_lens_latency_ms_bucket`` with cumulative ``le`` labels plus
+  ``_sum``/``_count`` under ``# TYPE ... histogram`` (the summary-style
+  quantile emission in :mod:`obs.export` cannot be aggregated across
+  instances; these can).
 - :class:`RegressionSentinel` — a background comparator (the
-  InvariantSweeper worker pattern, :mod:`obs.audit`) testing each series'
-  live window against a rolling reference window and committed BENCH
-  baselines. Sustained p50/p99 regression raises an ``A_REGRESSION``
-  flight anomaly (rate-limited dump machinery rides for free) and a
-  ``geomesa_lens_regression`` gauge.
+  InvariantSweeper worker pattern, :mod:`obs.audit`) testing each plan
+  signature's live window against a rolling reference window and
+  committed BENCH baselines. Sustained p50/p99 regression raises an
+  ``A_REGRESSION`` flight anomaly (rate-limited dump machinery rides for
+  free) and a ``geomesa_lens_regression`` gauge.
 
-Overhead discipline: ``observe()`` is on the always-on query path — one
-leaf-lock acquisition, a bisect into 15 fixed edges, and a handful of
-increments (the <2% cached-jit select bound is gated in scripts/lint.sh).
 No jax anywhere (``GEOMESA_TPU_NO_JAX=1`` safe).
 
-Locking: the lens owns ONE leaf lock for the series table + buckets
+Locking: a ring owns ONE leaf lock for its series table + buckets
 (metrics tier, docs/concurrency.md) — quantile math and exposition copy
 under the lock, format outside it. The sentinel's state is its own leaf.
 """
@@ -42,15 +36,13 @@ from __future__ import annotations
 
 import threading
 import time
-from bisect import bisect_left
 from collections import deque
 
-from geomesa_tpu.analysis.contracts import (cache_surface, feedback_sink,
-                                            shadow_plane)
+from geomesa_tpu.analysis.contracts import shadow_plane
 
 __all__ = [
-    "HistogramRing", "LatencyLens", "RegressionSentinel", "BUCKET_EDGES_MS",
-    "get", "install", "sentinel", "install_sentinel",
+    "HistogramRing", "RegressionSentinel", "BUCKET_EDGES_MS",
+    "histogram_lines", "sentinel", "install_sentinel",
 ]
 
 # fixed log-scale latency bin edges (ms). Fixed — not adaptive — so bucket
@@ -66,14 +58,14 @@ _N_BINS = len(BUCKET_EDGES_MS) + 1  # + the +Inf overflow bin
 
 _BUCKET_S = 10.0  # time-bucket width (matches the SLO engine's counters)
 _RING = 360  # buckets retained per series (1 h at 10 s)
-_MAX_SERIES = 256  # (type, signature) cardinality valve
+_MAX_SERIES = 256  # default series cardinality valve
 EXEMPLARS_PER_BUCKET = 4  # slowest traced queries kept per bucket
 
 
 class _LensBucket:
     """One time bucket of one series: a latency histogram plus rollups and
     the bucket's slowest traced exemplars. Mutated only under the owning
-    lens's lock."""
+    ring's lock."""
 
     __slots__ = ("start", "bins", "count", "sum_ms", "max_ms", "rows",
                  "dispatches", "exemplars")
@@ -137,14 +129,15 @@ class HistogramRing:
     cardinality valve, the time-bucket ring append, the exemplar
     replace-min, and the merged-window histogram math.
 
-    Both lenses — the query lens below and the stream delivery lens
-    (:mod:`geomesa_tpu.obs.streamlens`) — are subclasses, so the ring /
-    valve / exemplar semantics cannot drift between the two planes.
-    Subclasses pick their bucket and series classes via ``_bucket_cls`` /
-    ``_series_cls`` (extra ``__slots__`` on top of :class:`_LensBucket`)
-    and may override :meth:`_evict_locked` with their own valve policy
-    (the query lens drops the longest-idle series; the stream lens drops
-    the cheapest and folds it into an ``other`` rollup).
+    Both rings — the plan table (:mod:`geomesa_tpu.obs.plantable`) and
+    the stream delivery lens (:mod:`geomesa_tpu.obs.streamlens`) — are
+    subclasses, so the ring / exemplar semantics cannot drift between the
+    two planes. Subclasses pick their bucket and series classes via
+    ``_bucket_cls`` / ``_series_cls`` (extra ``__slots__`` on top of
+    :class:`_LensBucket` / :class:`_Series`) and supply the valve,
+    :meth:`_evict_locked` (the plan table drops the record written
+    longest ago; the stream lens drops the cheapest series and folds it
+    into an ``other`` rollup).
 
     Locking: ONE leaf lock for the series table + buckets (metrics tier,
     docs/concurrency.md) — every ``*_locked`` helper assumes it is held;
@@ -164,23 +157,18 @@ class HistogramRing:
         self.observe_count = 0
 
     # -- shared machinery (caller holds self._lock) ---------------------------
-    def _evict_locked(self) -> None:
-        """Cardinality valve: drop the series with the oldest newest-
-        bucket (longest idle). Subclasses may override the policy."""
-        idle = min(
-            self._series,
-            key=lambda k: (self._series[k].buckets[-1].start
-                           if self._series[k].buckets else 0.0))
-        del self._series[idle]
-
-    def _bucket_locked(self, key: tuple, now: float):
-        """The series' bucket covering ``now`` (creating series and
-        bucket as needed; the valve runs on series creation)."""
+    def _series_locked(self, key: tuple):
+        """The key's series, created as needed (the subclass's valve,
+        ``_evict_locked``, runs on creation)."""
         series = self._series.get(key)
         if series is None:
             if len(self._series) >= self._max_series:
                 self._evict_locked()
             series = self._series[key] = self._series_cls(self._ring)
+        return series
+
+    def _bucket_locked(self, series, now: float):
+        """``series``' bucket covering ``now``, appended as needed."""
         start = now - (now % self.bucket_s)
         if series.buckets and series.buckets[-1].start == start:
             return series.buckets[-1]
@@ -239,165 +227,30 @@ class HistogramRing:
             return list(self._series)
 
 
-@cache_surface(name="query-lens", keyed_by="type_name", purge=("forget",))
-class LatencyLens(HistogramRing):
-    """The retained profiling plane: bounded time-bucketed latency
-    histogram rings per (type, plan signature), with trace exemplars.
-    Series for a dropped/renamed type are purged via :meth:`forget`
-    (``DataStore._purge_type_name``)."""
-
-    # -- the hot path ---------------------------------------------------------
-    @feedback_sink
-    def observe(self, type_name: str, signature: str, latency_ms: float,
-                rows: int = 0, dispatches: int = 0, trace_id: str = "",
-                now: float | None = None) -> None:
-        """One completed query. Always-on: one lock, one bisect, a few
-        increments; the exemplar replace-min only runs for traced queries
-        landing in a bucket's current top-``EXEMPLARS_PER_BUCKET``."""
-        if now is None:
-            now = self._clock()
-        key = (type_name, signature)
-        bin_i = bisect_left(BUCKET_EDGES_MS, latency_ms)
-        with self._lock:
-            b = self._bucket_locked(key, now)
-            b.bins[bin_i] += 1
-            b.count += 1
-            b.sum_ms += latency_ms
-            if latency_ms > b.max_ms:
-                b.max_ms = latency_ms
-            b.rows += rows
-            b.dispatches += dispatches
-            if trace_id:
-                self._exemplar_locked(b, latency_ms, trace_id, now)
-            self.observe_count += 1
-
-    # -- maintenance ----------------------------------------------------------
-    def forget(self, type_name: str) -> None:
-        """Purge every series for ``type_name`` (schema delete/rename)."""
-        with self._lock:
-            for key in [k for k in self._series if k[0] == type_name]:
-                del self._series[key]
-
-    # -- read surfaces --------------------------------------------------------
-    def window_stats(self, type_name: str, signature: str,
-                     start_s: float, end_s: float) -> dict:
-        """Merged stats over buckets intersecting ``[start_s, end_s)``:
-        count / sum / p50 / p95 / p99 / max / rows / dispatches. The
-        sentinel's comparison primitive."""
-        extra = {"rows": 0, "dispatches": 0}
-
-        def fold(b):
-            extra["rows"] += b.rows
-            extra["dispatches"] += b.dispatches
-
-        with self._lock:
-            bins, count, sum_ms, max_ms = self._window_locked(
-                (type_name, signature), start_s, end_s, fold)
-        rows = extra["rows"]
-        dispatches = extra["dispatches"]
-        return {
-            "count": count,
-            "sum_ms": sum_ms,
-            "mean_ms": sum_ms / count if count else 0.0,
-            "p50_ms": _quantile(bins, count, 0.5),
-            "p95_ms": _quantile(bins, count, 0.95),
-            "p99_ms": _quantile(bins, count, 0.99),
-            "max_ms": max_ms,
-            "rows": rows,
-            "dispatches": dispatches,
-        }
-
-    def exemplars(self, type_name: str, signature: str,
-                  limit: int = 16) -> list:
-        """The series' retained exemplars, slowest first:
-        ``{latency_ms, trace_id, ts, bucket}`` — each trace_id resolves
-        against ``trace.recent()`` (and flight dumps) to the stitched
-        span tree."""
-        with self._lock:
-            rows = self._exemplar_rows_locked((type_name, signature))
-        rows.sort(key=lambda r: -r["latency_ms"])
-        return rows[:limit]
-
-    def snapshot(self, limit: int = 50, window_s: float = 300.0,
-                 type_name: str | None = None) -> dict:
-        """The ``/api/obs/lens`` payload: per-series live-window quantiles,
-        the retained bucket series (start/count/mean/max), and the top
-        exemplars."""
-        now = self._clock()
-        with self._lock:
-            keys = [k for k in self._series
-                    if type_name is None or k[0] == type_name]
-        entries = []
-        for t, sig in keys:
-            win = self.window_stats(t, sig, now - window_s, now + 1.0)
-            with self._lock:
-                series = self._series.get((t, sig))
-                buckets = [
-                    {"ts": b.start, "count": b.count,
-                     "mean_ms": round(b.sum_ms / b.count, 3) if b.count else 0.0,
-                     "max_ms": round(b.max_ms, 3),
-                     "rows": b.rows, "dispatches": b.dispatches}
-                    for b in (series.buckets if series is not None else ())
-                ]
-            entries.append({
-                "type": t,
-                "signature": sig,
-                "window_s": window_s,
-                "window": {k: (round(v, 3) if isinstance(v, float) else v)
-                           for k, v in win.items()},
-                "buckets": buckets[-64:],
-                "exemplars": self.exemplars(t, sig, limit=8),
-            })
-        entries.sort(key=lambda e: -e["window"]["count"])
-        return {
-            "entries": entries[:limit],
-            "series": len(keys),
-            "bucket_s": self.bucket_s,
-            "observe_count": self.observe_count,
-        }
-
-    # -- prometheus exposition ------------------------------------------------
-    def prometheus_lines(self, prefix: str = "geomesa") -> list[str]:
-        """TRUE histogram families over the retained ring: per series,
-        cumulative ``_bucket`` counts with ``le`` labels (``+Inf`` bucket
-        equals ``_count``), plus ``_sum``/``_count`` — and a companion
-        ``_dispatches_total`` counter. Empty when nothing observed."""
-        with self._lock:
-            rows = []
-            for (t, sig), series in self._series.items():
-                bins = [0] * _N_BINS
-                count = 0
-                sum_ms = 0.0
-                dispatches = 0
-                for b in series.buckets:
-                    for i, c in enumerate(b.bins):
-                        bins[i] += c
-                    count += b.count
-                    sum_ms += b.sum_ms
-                    dispatches += b.dispatches
-                rows.append((t, sig, bins, count, sum_ms, dispatches))
-        if not rows:
-            return []
-        name = f"{prefix}_lens_latency_ms"
-        hist = [f"# TYPE {name} histogram"]
-        disp = [f"# TYPE {prefix}_lens_dispatches_total counter"]
-        for t, sig, bins, count, sum_ms, dispatches in rows:
-            labels = f'type="{_esc(t)}",signature="{_esc(sig)}"'
-            cum = 0
-            for i, edge in enumerate(BUCKET_EDGES_MS):
-                cum += bins[i]
-                hist.append(
-                    f'{name}_bucket{{{labels},le="{_fmt_le(edge)}"}} {cum}')
-            hist.append(f'{name}_bucket{{{labels},le="+Inf"}} {count}')
-            hist.append(f"{name}_sum{{{labels}}} {sum_ms:.6g}")
-            hist.append(f"{name}_count{{{labels}}} {count}")
-            disp.append(
-                f"{prefix}_lens_dispatches_total{{{labels}}} {dispatches}")
-        return hist + disp
-
-    def prometheus_text(self, prefix: str = "geomesa") -> str:
-        lines = self.prometheus_lines(prefix)
-        return "\n".join(lines) + "\n" if lines else ""
+def histogram_lines(rows, prefix: str = "geomesa") -> list[str]:
+    """TRUE histogram families over merged rings: per ``(type, signature,
+    bins, count, sum_ms, dispatches)`` row, cumulative ``_bucket`` counts
+    with ``le`` labels (``+Inf`` bucket equals ``_count``), plus
+    ``_sum``/``_count`` — and a companion ``_dispatches_total`` counter.
+    Empty when there are no rows."""
+    if not rows:
+        return []
+    name = f"{prefix}_lens_latency_ms"
+    hist = [f"# TYPE {name} histogram"]
+    disp = [f"# TYPE {prefix}_lens_dispatches_total counter"]
+    for t, sig, bins, count, sum_ms, dispatches in rows:
+        labels = f'type="{_esc(t)}",signature="{_esc(sig)}"'
+        cum = 0
+        for i, edge in enumerate(BUCKET_EDGES_MS):
+            cum += bins[i]
+            hist.append(
+                f'{name}_bucket{{{labels},le="{_fmt_le(edge)}"}} {cum}')
+        hist.append(f'{name}_bucket{{{labels},le="+Inf"}} {count}')
+        hist.append(f"{name}_sum{{{labels}}} {sum_ms:.6g}")
+        hist.append(f"{name}_count{{{labels}}} {count}")
+        disp.append(
+            f"{prefix}_lens_dispatches_total{{{labels}}} {dispatches}")
+    return hist + disp
 
 
 # -- regression sentinel ------------------------------------------------------
@@ -408,7 +261,8 @@ class RegressionSentinel:
     InvariantSweeper worker shape: ``start()``/``close()`` around a
     daemon thread, ``evaluate_once()`` for tests and the CLI).
 
-    Per evaluation, for every lens series with enough live traffic:
+    Per evaluation, for every plan signature of the plan table
+    (:mod:`geomesa_tpu.obs.plantable`) with enough live traffic:
 
     - live window = the trailing ``live_window_s``;
     - reference = the ``ref_window_s`` immediately before it (rolling);
@@ -422,14 +276,14 @@ class RegressionSentinel:
     ``geomesa_lens_regression`` gauge until the series recovers.
 
     Evaluations run in audit shadow: sentinel reads must never train the
-    cost table, bill a tenant, or re-enter the lens."""
+    cost table, bill a tenant, or re-enter the plan table."""
 
-    def __init__(self, lens: LatencyLens | None = None,
+    def __init__(self, table=None,
                  interval_s: float = 30.0, live_window_s: float = 60.0,
                  ref_window_s: float = 600.0, factor: float = 1.5,
                  min_live: int = 16, min_ref: int = 16, sustain: int = 1,
                  clock=time.time):
-        self._lens = lens
+        self._table = table
         self.interval_s = interval_s
         self.live_window_s = live_window_s
         self.ref_window_s = ref_window_s
@@ -448,8 +302,13 @@ class RegressionSentinel:
         self.regressions_total = 0
 
     @property
-    def lens(self) -> LatencyLens:
-        return self._lens if self._lens is not None else get()
+    def table(self):
+        """The plan table judged (the process table unless injected)."""
+        if self._table is not None:
+            return self._table
+        from geomesa_tpu.obs import plantable
+
+        return plantable.table()
 
     def load_baselines(self, baselines: dict) -> int:
         """Install committed reference medians: ``{"type:signature":
@@ -478,15 +337,15 @@ class RegressionSentinel:
             return self._evaluate(self._clock() if now is None else now)
 
     def _evaluate(self, now: float) -> list[dict]:
-        lens = self.lens
+        tbl = self.table
         raised = []
         live_lo = now - self.live_window_s
         ref_lo = live_lo - self.ref_window_s
-        for t, sig in lens.series_keys():
-            live = lens.window_stats(t, sig, live_lo, now + 1.0)
+        for t, sig in tbl.series_keys():
+            live = tbl.window_stats(t, sig, live_lo, now + 1.0)
             if live["count"] < self.min_live:
                 continue  # not enough live traffic to judge — hold state
-            ref = lens.window_stats(t, sig, ref_lo, live_lo)
+            ref = tbl.window_stats(t, sig, ref_lo, live_lo)
             with self._lock:
                 base = self._baselines.get((t, sig))
             causes = []
@@ -599,21 +458,8 @@ class RegressionSentinel:
         return "\n".join(self.prometheus_lines(prefix)) + "\n"
 
 
-# process-wide singletons (tests swap with install()/install_sentinel())
-_lens = LatencyLens()
+# process-wide singleton (tests swap with install_sentinel())
 _sentinel = RegressionSentinel()
-
-
-def get() -> LatencyLens:
-    """The process-wide lens."""
-    return _lens
-
-
-def install(lens: LatencyLens) -> LatencyLens:
-    """Swap the process lens (tests); returns the previous one."""
-    global _lens
-    prev, _lens = _lens, lens
-    return prev
 
 
 def sentinel() -> RegressionSentinel:

@@ -203,13 +203,12 @@ class StreamLens(HistogramRing):
         bin_i = (bisect_left(BUCKET_EDGES_MS, latency_ms)
                  if latency_ms is not None else 0)
         with self._lock:
-            series = self._series.get(key)
-            if series is None:
+            if key not in self._series:
                 # touch the topic table first so the valve's rollup
                 # target exists before any eviction can need it
                 self._topic_locked(topic)
-            b = self._bucket_locked(key, now)
-            series = self._series[key]
+            series = self._series_locked(key)
+            b = self._bucket_locked(series, now)
             series.chunks += 1
             b.dispatches += 1
             series.cost += cost

@@ -12,11 +12,12 @@ This module closes the loop:
   (:meth:`geomesa_tpu.stats.store_stats.StoreStats.selectivity`): a row
   estimate converted to a synthetic cost, used only for RELATIVE ranking
   until real observations exist.
-- **Observations** come from the devmon :class:`~geomesa_tpu.obs.devmon.
-  CostTable` (``/api/obs/costs``), fed by every fully-planned query audit
-  and by the per-route ``sel:*`` / ``gagg:*`` / ``join:*`` signatures the
-  dispatch layers record. Once every candidate of a decision has enough
-  observations, measured p50 wall-ms replaces the seed ranking outright.
+- **Observations** come from the cost profiles of the plan table
+  (:mod:`geomesa_tpu.obs.plantable`, ``/api/obs/costs``), fed by every
+  fully-planned query audit and by the per-route ``sel:*`` / ``gagg:*`` /
+  ``join:*`` signatures the dispatch layers record. Once every candidate
+  of a decision has enough observations, measured p50 wall-ms replaces
+  the seed ranking outright.
 - **Bounded exploration** (the generalized ``choose_agg_path`` tick/probe
   mechanism): every ``PROBE_EVERY``-th consult of a decision routes to the
   LOSING candidate so no profile freezes — the winner can never starve the
@@ -28,27 +29,23 @@ This module closes the loop:
   near-tied candidates (within ``TIE_BAND``) resolve to the LOWER-VARIANCE
   plan (smallest p95/p50 spread) — under burn, predictability beats a thin
   median win.
-- **Calibration**: every (predicted, actual) pair lands in an online
-  per-(type, signature) calibration table — mean absolute relative error,
-  signed bias — served with ``/api/obs/costs`` and rendered by
-  ``explain(analyze=True)``, so model drift is observable before it costs
-  latency.
+- **Calibration**: every (predicted, actual) pair of a planned query
+  lands in the plan table's per-(type, signature) calibration sums — mean
+  absolute relative error, signed bias — served with ``/api/obs/costs``
+  and rendered by ``explain(analyze=True)``, so model drift is observable
+  before it costs latency.
 
-Locking: one leaf lock for the calibration table (same tier as the devmon
-locks, docs/concurrency.md). No jax at module level
-(``GEOMESA_TPU_NO_JAX=1`` safe).
+The model keeps no state of its own: every decision reads the plan
+table. No jax at module level (``GEOMESA_TPU_NO_JAX=1`` safe).
 """
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass, field
-
-from geomesa_tpu.analysis.contracts import cache_surface, feedback_sink
 
 __all__ = [
     "Candidate", "CostModel", "MIN_OBSERVATIONS", "PROBE_EVERY",
-    "PROBE_MAX_RATIO", "TIE_BAND", "install", "model",
+    "PROBE_MAX_RATIO", "TIE_BAND", "model",
 ]
 
 # observations a signature needs before its measured p50 outranks seeds
@@ -91,51 +88,32 @@ class Candidate:
         return float("inf")
 
 
-class _Calibration:
-    __slots__ = ("count", "abs_rel_err_sum", "signed_rel_err_sum",
-                 "last_predicted", "last_actual")
-
-    def __init__(self):
-        self.count = 0
-        self.abs_rel_err_sum = 0.0
-        self.signed_rel_err_sum = 0.0
-        self.last_predicted = 0.0
-        self.last_actual = 0.0
-
-
 def calibration_error(predicted_ms: float, actual_ms: float) -> float:
     """Relative prediction error vs the ACTUAL cost: |pred - actual| /
     max(actual, epsilon). 0.0 = perfect; 1.0 = off by the full actual."""
     return abs(predicted_ms - actual_ms) / max(actual_ms, 1e-6)
 
 
-@cache_surface(name="planner-calibration-table", keyed_by="type_name",
-               purge=("forget",))
 class CostModel:
     """The decision engine: rank candidates by learned cost when every
     candidate is trained, by stats seeds otherwise; probe the loser on a
-    bounded schedule; track prediction calibration."""
+    bounded schedule."""
 
     def __init__(self, table=None, min_observations: int = MIN_OBSERVATIONS,
-                 probe_every: int = PROBE_EVERY, max_entries: int = 256):
+                 probe_every: int = PROBE_EVERY):
         self._table = table
         self.min_observations = min_observations
         self.probe_every = probe_every
-        self._cal_lock = threading.Lock()  # leaf: calibration entries
-        from collections import OrderedDict
-
-        self._cal: "OrderedDict[tuple, _Calibration]" = OrderedDict()
-        self._cal_max = max_entries
 
     def table(self):
-        """The live observed-cost table (the devmon singleton unless one
-        was injected for tests) — resolved per call so test installs via
-        ``devmon.install`` are honored."""
+        """The live plan table (the process table unless one was
+        injected for tests) — resolved per call so test installs via
+        ``plantable.install`` are honored."""
         if self._table is not None:
             return self._table
-        from geomesa_tpu.obs import devmon
+        from geomesa_tpu.obs import plantable
 
-        return devmon.costs()
+        return plantable.table()
 
     # -- prediction ----------------------------------------------------------
     def predict(self, type_name: str, signature: str,
@@ -263,105 +241,6 @@ class CostModel:
         ])
         return win.name
 
-    # -- calibration ---------------------------------------------------------
-    @feedback_sink
-    def record_calibration(self, type_name: str, signature: str,
-                           predicted_ms: float, actual_ms: float) -> None:
-        err = calibration_error(predicted_ms, actual_ms)
-        signed = (predicted_ms - actual_ms) / max(actual_ms, 1e-6)
-        key = (type_name, signature)
-        with self._cal_lock:
-            e = self._cal.get(key)
-            if e is None:
-                e = self._cal[key] = _Calibration()
-                while len(self._cal) > self._cal_max:
-                    self._cal.popitem(last=False)
-            else:
-                self._cal.move_to_end(key)
-            e.count += 1
-            e.abs_rel_err_sum += err
-            e.signed_rel_err_sum += signed
-            e.last_predicted = float(predicted_ms)
-            e.last_actual = float(actual_ms)
-
-    def forget(self, type_name: str) -> None:
-        """Drop one type's calibration rows (schema delete/rename — the
-        cost-table ``forget`` analog)."""
-        with self._cal_lock:
-            for k in [k for k in self._cal if k[0] == type_name]:
-                del self._cal[k]
-
-    # -- persistence (the workload-dir cost sidecar, obs.devmon) -------------
-    def calibration_state(self) -> dict:
-        """JSON-able calibration state — saved with the cost-table
-        snapshot so predicted-vs-actual drift accounting survives
-        restarts alongside the p50 rankings it judges."""
-        with self._cal_lock:
-            entries = [
-                {"type": t, "signature": sig, "count": e.count,
-                 "abs_rel_err_sum": round(e.abs_rel_err_sum, 6),
-                 "signed_rel_err_sum": round(e.signed_rel_err_sum, 6),
-                 "last_predicted": round(e.last_predicted, 4),
-                 "last_actual": round(e.last_actual, 4)}
-                for (t, sig), e in self._cal.items()
-            ]
-        return {"entries": entries}
-
-    def load_calibration_state(self, state: dict) -> None:
-        """Restore :meth:`calibration_state` (same merge-by-richness
-        semantics as ``CostTable.load_state``: a snapshot row never
-        regresses a live entry that has already learned past it)."""
-        for row in state.get("entries", []):
-            key = (row["type"], row["signature"])
-            e = _Calibration()
-            e.count = int(row.get("count", 0))
-            e.abs_rel_err_sum = float(row.get("abs_rel_err_sum", 0.0))
-            e.signed_rel_err_sum = float(row.get("signed_rel_err_sum", 0.0))
-            e.last_predicted = float(row.get("last_predicted", 0.0))
-            e.last_actual = float(row.get("last_actual", 0.0))
-            with self._cal_lock:
-                live = self._cal.get(key)
-                if live is not None and live.count >= e.count:
-                    continue
-                self._cal[key] = e
-                self._cal.move_to_end(key)
-                while len(self._cal) > self._cal_max:
-                    self._cal.popitem(last=False)
-
-    def calibration_report(self) -> dict:
-        """The drift surface served with ``GET /api/obs/costs``: per-(type,
-        signature) mean absolute relative error (MAPE vs actual), signed
-        bias (positive = over-prediction), sample count, and the last
-        predicted/actual pair; plus an overall observation-weighted MAPE."""
-        with self._cal_lock:
-            items = [(k, e.count, e.abs_rel_err_sum, e.signed_rel_err_sum,
-                      e.last_predicted, e.last_actual)
-                     for k, e in self._cal.items()]
-        rows = []
-        tot_n = 0
-        tot_err = 0.0
-        for (t, sig), n, abs_sum, signed_sum, lp, la in items:
-            rows.append({
-                "type": t,
-                "signature": sig,
-                "count": n,
-                "mean_abs_rel_err": round(abs_sum / n, 4),
-                "mean_signed_rel_err": round(signed_sum / n, 4),
-                "last_predicted_ms": round(lp, 3),
-                "last_actual_ms": round(la, 3),
-            })
-            tot_n += n
-            tot_err += abs_sum
-        rows.sort(key=lambda r: (r["type"], r["signature"]))
-        return {
-            "entries": rows,
-            "entry_count": len(rows),
-            "overall_mean_abs_rel_err": (
-                round(tot_err / tot_n, 4) if tot_n else None
-            ),
-            "samples": tot_n,
-        }
-
 
 def _spread(observed: dict | None) -> float:
     """p95/p50 dispersion — the variance proxy SLO tie-breaking minimizes
@@ -382,12 +261,3 @@ _model = CostModel()
 
 def model() -> CostModel:
     return _model
-
-
-def install(new_model: "CostModel | None" = None) -> CostModel:
-    """Swap the process singleton (test isolation); returns the previous
-    model. Pass None to reset to a fresh default model."""
-    global _model
-    prev = _model
-    _model = new_model if new_model is not None else CostModel()
-    return prev

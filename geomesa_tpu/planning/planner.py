@@ -626,7 +626,7 @@ def choose_agg_path(cost_table, type_name: str,
     exactly its regime), and every ``AGG_PROBE_EVERY``-th consult routes
     to the LOSING path so neither profile freezes. The probe schedule
     rides the cost table's per-type consult counter
-    (:meth:`CostTable.tick`) — never observation counts, which the
+    (:meth:`PlanTable.tick`) — never observation counts, which the
     winner freezes by starving the loser of observations."""
     from geomesa_tpu.planning.costmodel import CostModel
 

@@ -296,7 +296,7 @@ def _observe_join(ds, type_name: str, route: str, t0: float,
 
     from geomesa_tpu.obs import devmon
 
-    devmon.costs().observe(
+    devmon.costs().observe_cost(
         type_name, f"join:{route}",
         wall_ms=(_time.perf_counter() - t0) * 1000.0, rows=rows,
     )

@@ -607,7 +607,7 @@ class TpuBackend(ExecutionBackend):
             # audit-shadow re-executions (the divergence minimizer runs
             # the live path repeatedly) must not train the sel:* route
             # profiles — same hygiene as the _audit-level exclusions
-            devmon.costs().observe(
+            devmon.costs().observe_cost(
                 type_name, f"sel:{route}",
                 wall_ms=(_time.perf_counter() - t0) * 1000.0,
                 rows=len(positions),

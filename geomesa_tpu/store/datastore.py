@@ -369,6 +369,7 @@ class DataStore:
 
         self.slo = SloEngine()
         self.slo.objective("store.query", target=0.999)
+        self._slo_feed = 0  # audits since start: pool SLO feed sampling
         # GeoBlocks query cache (ops/geoblocks.py): exact-repeat grouped
         # aggregations served straight from cache, epoch-validated so a
         # write can never leave a stale answer servable
@@ -562,10 +563,9 @@ class DataStore:
 
     @mutation(kind="evolve", invalidates=("plan-cache", "agg-pyramids"))
     @mutation(kind="rename", invalidates=(
-        "geoblocks-query-cache", "buffer-pool", "device-cost-table",
-        "spill-ledger", "planner-calibration-table",
-        "persisted-cost-sidecar", "track-state-cache",
-        "query-lens", "roundtrip-ledger", "stream-lens"))
+        "geoblocks-query-cache", "buffer-pool", "plan-table",
+        "spill-ledger", "persisted-cost-sidecar", "track-state-cache",
+        "stream-lens"))
     def update_schema(
         self,
         type_name: str,
@@ -709,10 +709,9 @@ class DataStore:
         return sorted(self._types)
 
     @mutation(kind="delete_schema", invalidates=(
-        "geoblocks-query-cache", "buffer-pool", "device-cost-table",
-        "spill-ledger", "planner-calibration-table",
-        "persisted-cost-sidecar", "track-state-cache",
-        "query-lens", "roundtrip-ledger", "stream-lens"))
+        "geoblocks-query-cache", "buffer-pool", "plan-table",
+        "spill-ledger", "persisted-cost-sidecar", "track-state-cache",
+        "stream-lens"))
     def delete_schema(self, name: str) -> None:
         if self._wal_active():
             from geomesa_tpu.store import wal as _walmod
@@ -749,21 +748,12 @@ class DataStore:
         pool: "BufferPool | None" = getattr(self.backend, "pool", None)
         if pool is not None:
             pool.purge(name)
-        from geomesa_tpu.obs import devmon
-        from geomesa_tpu.planning import costmodel
+        from geomesa_tpu.obs import devmon, plantable
 
         devmon.ledger().clear_spills(name)
-        devmon.costs().forget(name)
-        costmodel.model().forget(name)
-        # the retained profiling plane (obs.lens) and the roundtrip rollup
-        # (obs.ledger) key series by type name too: a recreated same-name
-        # type must not inherit its predecessor's latency history or
-        # fusion ranking (and the sentinel must not compare across them)
-        from geomesa_tpu.obs import lens as _lensmod
-        from geomesa_tpu.obs import ledger as _rtledger
-
-        _lensmod.get().forget(name)
-        _rtledger.table().forget(name)
+        # the plan table: cost profile, probe phase, calibration, latency
+        # history and fusion ranking of every signature of the type
+        plantable.table().forget(name)
         # the stream lens keys delivery history by TOPIC, and the topic
         # convention is type-name-derived — a recreated same-name type's
         # standing subscriptions must not inherit the dead type's
@@ -2686,7 +2676,7 @@ class DataStore:
         # verdict (the same hygiene _audit applies to the cost table)
         _observe_gagg = (
             (lambda *a, **k: None) if _obsaudit.in_shadow()
-            else devmon_costs.observe
+            else devmon_costs.observe_cost
         )
         cache_ctx = None
         if isinstance(self.backend, TpuBackend) and ttl is None:
@@ -3142,16 +3132,19 @@ class DataStore:
         self.metrics.histogram("store.query.hits").update(hits)
         self.metrics.histogram("store.query.scan_ms").update(scan_ms)
         filt = q.filter if isinstance(q.filter, str) else str(q.filter or "INCLUDE")
-        # always-on observability: one flight-recorder audit record + one
-        # SLO availability observation + one cost-table observation per
+        # always-on observability: one flight-recorder audit record, one
+        # SLO availability observation and one plan-table write per
         # completed query (all leaf-lock appends — the <2% cached-jit
         # bound is gated in scripts/lint.sh). A query that ran under
         # devprof additionally carries its device-time breakdown.
-        from geomesa_tpu.obs import devmon, usage, workload
+        from geomesa_tpu.obs import devmon, plantable, usage, workload
         from geomesa_tpu.obs import flight as _flight
+        from geomesa_tpu.obs import ledger as _rtledger
 
         prof = devmon.current_profile() if devmon.PROFILING else None
         device = prof.breakdown() if prof is not None else None
+        device_ms = (device["device_compute"] + device["dispatch"]
+                     + device["compile"]) if device else None
         # tenant attribution (obs.usage): an explicit per-query hint wins
         # (the web layer sets it from X-Geomesa-Tenant); otherwise the
         # request-scoped context the web layer / replay harness bound —
@@ -3159,44 +3152,33 @@ class DataStore:
         tenant = q.hints.get("tenant") or usage.current_tenant()
         # batched paths (select_many) pass their planned signature
         # explicitly: they audit with info=None (amortized timings must
-        # not train the cost table) but their lens/ledger attribution
-        # still keys on the REAL plan signature
+        # not train the cost profile) but their latency and rollup
+        # attribution still keys on the REAL plan signature
         sig = sig if sig is not None else devmon.plan_signature(info, q)
-        predicted = None
-        # only FULLY PLANNED, individually timed executions feed the cost
-        # table: batched paths audit with amortized-zero timings and no
-        # plan info, and an empty store audits 0 ms — letting those in
-        # would pull every p50 toward zero under the wrong signature
-        # (the table is the adaptive planner's training signal)
-        if info is not None:
-            index_name = getattr(info, "index_name", None) or ""
-            costs = devmon.costs()
-            # predicted-vs-actual calibration: read the table's p50 BEFORE
-            # this run observes into it (what the planner would have
-            # predicted), then feed the error into the cost model's drift
-            # report (/api/obs/costs "calibration" section)
-            predicted = costs.predict(type_name, sig)
-            costs.observe(
-                type_name, sig,
-                wall_ms=plan_ms + scan_ms,
-                device_ms=(device["device_compute"] + device["dispatch"]
-                           + device["compile"]) if device else None,
-                rows=hits,
-                bytes_scanned=(
-                    devmon.ledger().index_bytes(type_name, index_name)
-                    if index_name and "union" not in index_name else 0
-                ),
-            )
-            if predicted is not None and predicted.get("observations", 0) >= 4:
-                from geomesa_tpu.planning import costmodel
-
-                costmodel.model().record_calibration(
-                    type_name, sig,
-                    predicted["wall_ms_p50"], plan_ms + scan_ms,
-                )
+        index_name = getattr(info, "index_name", None) or ""
+        # a coalesced follower's stamped trace_id hint wins over the
+        # leader's batch span, so exemplars resolve to DISJOINT trees
+        trace_id = q.hints.get("trace_id") or ""
+        if not trace_id:
+            sp = obs.current()
+            trace_id = sp.trace_id if sp is not None else ""
+        # the one plan-table write: only FULLY PLANNED, individually timed
+        # executions train the cost profile and its calibration (batched
+        # paths audit with amortized-zero timings and no plan info, and an
+        # empty store audits 0 ms — letting those in would pull every p50
+        # toward zero under the wrong signature); every query adds its
+        # latency point and charges its roundtrip ledger (every member of
+        # a coalesced batch charges the SHARED ledger once)
+        predicted = plantable.table().record_query(
+            type_name, sig, plan_ms + scan_ms, rows=hits,
+            planned=info is not None, device_ms=device_ms,
+            bytes_scanned=(
+                devmon.ledger().index_bytes(type_name, index_name)
+                if index_name and "union" not in index_name else 0
+            ),
+            trace_id=trace_id, ledger=_rtledger.current())
         predicted_ms = predicted["wall_ms_p50"] if predicted else None
-        device_ms = (device["device_compute"] + device["dispatch"]
-                     + device["compile"]) if device else 0.0
+        device_ms = device_ms or 0.0
         _flight.record(
             op="query", type_name=type_name, source="store", plan=filt,
             latency_ms=plan_ms + scan_ms, rows=hits,
@@ -3207,28 +3189,6 @@ class DataStore:
         )
         self.slo.observe("store.query", ok=True, key=type_name,
                          latency_ms=plan_ms + scan_ms)
-        # retained profiling plane (obs.lens) + roundtrip rollup
-        # (obs.ledger): the lens takes the latency histogram point with
-        # the submitter's trace exemplar (a coalesced follower's stamped
-        # trace_id hint wins over the leader's batch span, so exemplars
-        # resolve to DISJOINT stitched trees); the rollup charges this
-        # query's dispatch/sync/host-gap ledger to its plan signature —
-        # every member of a coalesced batch charges the SHARED ledger once
-        from geomesa_tpu.obs import ledger as _rtledger
-        from geomesa_tpu.obs import lens as _lensmod
-
-        ql = _rtledger.current()
-        trace_id = q.hints.get("trace_id") or ""
-        if not trace_id:
-            sp = obs.current()
-            trace_id = sp.trace_id if sp is not None else ""
-        _lensmod.get().observe(
-            type_name, sig, latency_ms=plan_ms + scan_ms, rows=hits,
-            dispatches=ql.dispatches if ql is not None else 0,
-            trace_id=trace_id)
-        if ql is not None:
-            _rtledger.table().charge(type_name, sig, ql,
-                                     wall_ms=plan_ms + scan_ms)
         # per-tenant usage metering (obs.usage): one leaf-lock append, the
         # same cost class as the flight record — the accounting substrate
         # ROADMAP item 4's admission controller consumes
@@ -3254,7 +3214,7 @@ class DataStore:
         # its buffers stay resident while an idle type's go first
         pool = getattr(self.backend, "pool", None)
         if pool is not None:
-            self._slo_feed = getattr(self, "_slo_feed", 0) + 1
+            self._slo_feed += 1
             if self._slo_feed % 32 == 1:
                 pool.note_slo(
                     type_name,

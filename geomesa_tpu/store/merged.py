@@ -156,7 +156,7 @@ class MergedDataStoreView:
 
     def _note_member_cost(self, i: int, type_name: str, op: str,
                           ms: float) -> None:
-        from geomesa_tpu.obs.devmon import _Quantiles
+        from geomesa_tpu.obs.plantable import _Quantiles
 
         key = (i, type_name, op)
         with self._member_cost_lock:

@@ -426,7 +426,7 @@ def _observe_route(type_name: str, route: str, t0: float, rows: int) -> None:
 
     if _audit.in_shadow():
         return  # shadow re-executions must not train the traj profiles
-    devmon.costs().observe(
+    devmon.costs().observe_cost(
         type_name, f"traj:corridor-{'dev' if route == 'device' else 'host'}",
         wall_ms=(_time.perf_counter() - t0) * 1000.0, rows=rows)
 

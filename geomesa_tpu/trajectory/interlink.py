@@ -290,7 +290,7 @@ def _observe_link(rtype: str, route: str, t0: float, pairs: int) -> None:
 
     if _audit.in_shadow():
         return
-    devmon.costs().observe(
+    devmon.costs().observe_cost(
         rtype, f"traj:link-{route}",
         wall_ms=(_time.perf_counter() - t0) * 1000.0, rows=pairs)
 

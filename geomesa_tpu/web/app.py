@@ -1192,11 +1192,11 @@ class GeoMesaApp:
         error, signed bias, sample counts), so a model that has gone
         stale is visible before it costs latency (docs/planning.md)."""
         from geomesa_tpu.obs import devmon
-        from geomesa_tpu.planning import costmodel
 
         limit = self._int_param(params, "limit")
-        out = devmon.costs().snapshot(limit=limit or 256)
-        out["calibration"] = costmodel.model().calibration_report()
+        tbl = devmon.costs()
+        out = tbl.snapshot(limit=limit or 256)
+        out["calibration"] = tbl.calibration_report()
         # per-member observed-cost aggregates (merged/sharded views):
         # ?member=N filters to one member's rows
         member_costs = getattr(self.store, "member_costs_snapshot", None)
@@ -1224,6 +1224,7 @@ class GeoMesaApp:
         stitched span tree), plus the regression sentinel's alarm state —
         docs/observability.md § Query lens & host-roundtrip ledger."""
         from geomesa_tpu.obs import lens as _lensmod
+        from geomesa_tpu.obs import plantable
         from geomesa_tpu.obs import trace as _obstrace
 
         trace_id = params.get("trace")
@@ -1242,7 +1243,7 @@ class GeoMesaApp:
         except ValueError:
             return 400, {"error": f"bad window: {params['window']!r}"}, \
                 "application/json"
-        out = _lensmod.get().snapshot(
+        out = plantable.table().lens_snapshot(
             limit=limit or 50, window_s=window_s,
             type_name=params.get("type") or None)
         out["sentinel"] = _lensmod.sentinel().snapshot()
@@ -1379,8 +1380,9 @@ class GeoMesaApp:
             # _bucket/_sum/_count with le labels) per (type, signature),
             # plus the regression sentinel's gauge + counter
             from geomesa_tpu.obs import lens as _lensmod
+            from geomesa_tpu.obs import plantable
 
-            text += _lensmod.get().prometheus_text()
+            text += plantable.table().prometheus_text()
             text += _lensmod.sentinel().prometheus_text()
             # stream lens: geomesa_stream_delivery_* histogram families
             # per (topic, subscription) — top-K-by-cost + `other` rollup —
@@ -1441,10 +1443,11 @@ class GeoMesaApp:
         # query lens summary (full detail at GET /api/obs/lens): only once
         # something has been observed — plain scrapes skip the section
         from geomesa_tpu.obs import lens as _lensmod
+        from geomesa_tpu.obs import plantable
 
-        lens_obj = _lensmod.get()
-        if lens_obj.observe_count:
-            out["lens"] = lens_obj.snapshot(limit=8)
+        plans = plantable.table()
+        if plans.observe_count:
+            out["lens"] = plans.lens_snapshot(limit=8)
             out["lens"]["sentinel"] = _lensmod.sentinel().snapshot()
         # stream lens summary (full detail at GET /api/obs/stream)
         from geomesa_tpu.obs import streamlens as _slmod

@@ -30,7 +30,7 @@ def _capture(tmp: str) -> str:
 
     from geomesa_tpu.geometry.types import Point
     from geomesa_tpu.obs import ledger as ledger_mod
-    from geomesa_tpu.obs.ledger import LedgerTable
+    from geomesa_tpu.obs import plantable
     from geomesa_tpu.store import backends
     from geomesa_tpu.store.datastore import DataStore
 
@@ -49,7 +49,7 @@ def _capture(tmp: str) -> str:
     cql = "BBOX(geom,-50,-40,50,40)"
     backends._ONE_PASS_MAX_SLOTS = 0  # force the staged two-phase select
     ds.query("pts", cql)              # compile the staged steps
-    ledger_mod.install(LedgerTable())
+    plantable.install()
     for _ in range(3):
         ds.query("pts", cql)
     doc = ledger_mod.table().export()

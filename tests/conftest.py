@@ -44,20 +44,17 @@ def rng():
 @pytest.fixture(autouse=True)
 def _fresh_cost_state():
     """Per-test isolation of the adaptive-planner feedback state: the
-    process-global observed-cost table and cost model accumulate across
-    queries (probe ticks, learned route verdicts, calibration), so a
+    process-global plan table accumulates across queries (probe ticks,
+    learned route verdicts, calibration, latency history, rollups), so a
     suite run would otherwise leak one test's training into the next
     test's strategy choices — order-fragile by construction (the same
     lesson test_geoblocks learned for the agg route). Tests that manage
     their own installs simply stack on top; both restore on teardown."""
-    from geomesa_tpu.obs import devmon
-    from geomesa_tpu.planning import costmodel
+    from geomesa_tpu.obs import plantable
 
-    prev = devmon.install(new_costs=devmon.CostTable())
-    prev_model = costmodel.install()
+    prev = plantable.install()
     yield
-    devmon.install(new_costs=prev[1])
-    costmodel.install(prev_model)
+    plantable.install(prev)
 
 
 @pytest.fixture(scope="session", autouse=True)

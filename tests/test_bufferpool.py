@@ -41,13 +41,10 @@ class TestPoolUnit:
     """Pure pool mechanics against an isolated ledger."""
 
     def setup_method(self):
-        from geomesa_tpu.obs.devmon import CostTable
-
-        self.prev = devmon.install(new_ledger=ResidencyLedger(),
-                                   new_costs=CostTable())
+        self.prev = devmon.install(ResidencyLedger())
 
     def teardown_method(self):
-        devmon.install(new_ledger=self.prev[0], new_costs=self.prev[1])
+        devmon.install(self.prev)
 
     def _entry(self, pool, t, i, nbytes=100):
         owner = _Owner()
@@ -124,13 +121,10 @@ class TestPoolIntegration:
     """Pool behavior through real TpuBackend loads (tight budgets)."""
 
     def setup_method(self):
-        from geomesa_tpu.obs.devmon import CostTable
-
-        self.prev = devmon.install(new_ledger=ResidencyLedger(),
-                                   new_costs=CostTable())
+        self.prev = devmon.install(ResidencyLedger())
 
     def teardown_method(self):
-        devmon.install(new_ledger=self.prev[0], new_costs=self.prev[1])
+        devmon.install(self.prev)
 
     def _two_types(self):
         ds = DataStore(backend="tpu")

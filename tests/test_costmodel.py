@@ -14,8 +14,9 @@ import pytest
 from geomesa_tpu.filter import ast
 from geomesa_tpu.filter.cql import parse as parse_cql
 from geomesa_tpu.geometry import Point
-from geomesa_tpu.obs import devmon
-from geomesa_tpu.obs.devmon import CostTable, ResidencyLedger
+from geomesa_tpu.obs import devmon, plantable
+from geomesa_tpu.obs.devmon import ResidencyLedger
+from geomesa_tpu.obs.plantable import PlanTable
 from geomesa_tpu.planning import costmodel
 from geomesa_tpu.planning.costmodel import Candidate, CostModel
 from geomesa_tpu.planning.planner import (
@@ -36,12 +37,13 @@ SPEC = "name:String,age:Integer:index=true,dtg:Date,*geom:Point"
 
 @pytest.fixture()
 def fresh():
-    """Isolated cost table + cost model for the test; restored after."""
-    prev = devmon.install(ResidencyLedger(), CostTable())
-    prev_model = costmodel.install()
+    """Isolated residency ledger + plan table for the test; restored
+    after."""
+    prev = devmon.install(ResidencyLedger())
+    prev_table = plantable.install()
     yield
-    devmon.install(*prev)
-    costmodel.install(prev_model)
+    devmon.install(prev)
+    plantable.install(prev_table)
 
 
 def _fill(ds, n=3000, seed=7, type_name="evt"):
@@ -84,7 +86,7 @@ def _planner_fixture(n=3000, indices=None):
 
 class TestCostModelChoose:
     def test_seeded_ranking_before_training(self, fresh):
-        m = CostModel(table=CostTable())
+        m = CostModel(table=PlanTable())
         win, ranked, source = m.choose("t", "d", [
             Candidate("a", "sig:a", seed_ms=2.0),
             Candidate("b", "sig:b", seed_ms=1.0),
@@ -93,12 +95,12 @@ class TestCostModelChoose:
         assert [c.name for c in ranked] == ["b", "a"]
 
     def test_learned_override_beats_seeds(self, fresh):
-        ct = CostTable()
+        ct = PlanTable()
         m = CostModel(table=ct)
         # seeds say "b"; measurements say "a" is 10x faster
         for _ in range(10):
-            ct.observe("t", "sig:a", wall_ms=1.0)
-            ct.observe("t", "sig:b", wall_ms=10.0)
+            ct.observe_cost("t", "sig:a", wall_ms=1.0)
+            ct.observe_cost("t", "sig:b", wall_ms=10.0)
         win, _, source = m.choose("t", "d", [
             Candidate("a", "sig:a", seed_ms=2.0),
             Candidate("b", "sig:b", seed_ms=1.0),
@@ -106,10 +108,10 @@ class TestCostModelChoose:
         assert (win.name, source) == ("a", "cost-model")
 
     def test_partial_training_stays_on_seeds(self, fresh):
-        ct = CostTable()
+        ct = PlanTable()
         m = CostModel(table=ct)
         for _ in range(10):
-            ct.observe("t", "sig:a", wall_ms=1.0)  # only one side trained
+            ct.observe_cost("t", "sig:a", wall_ms=1.0)  # only one side trained
         win, _, source = m.choose("t", "d", [
             Candidate("a", "sig:a", seed_ms=2.0),
             Candidate("b", "sig:b", seed_ms=1.0),
@@ -117,11 +119,11 @@ class TestCostModelChoose:
         assert (win.name, source) == ("b", "stats")
 
     def test_probe_cadence_remeasures_loser(self, fresh):
-        ct = CostTable()
+        ct = PlanTable()
         m = CostModel(table=ct)
         for _ in range(10):
-            ct.observe("t", "sig:a", wall_ms=1.0)
-            ct.observe("t", "sig:b", wall_ms=10.0)
+            ct.observe_cost("t", "sig:a", wall_ms=1.0)
+            ct.observe_cost("t", "sig:b", wall_ms=10.0)
         picks = [
             m.choose("t", "d", [
                 Candidate("a", "sig:a", seed_ms=1.0),
@@ -143,7 +145,7 @@ class TestCostModelChoose:
     def test_probe_bounded_by_seed_ratio(self, fresh):
         """A candidate seeded catastrophically worse than the winner is
         never probed — bounded exploration."""
-        m = CostModel(table=CostTable())
+        m = CostModel(table=PlanTable())
         picks = [
             m.choose("t", "d", [
                 Candidate("cheap", "sig:a", seed_ms=1.0),
@@ -155,12 +157,12 @@ class TestCostModelChoose:
         assert picks.count("scan") == 0
 
     def test_slo_tie_break_prefers_low_variance(self, fresh):
-        ct = CostTable()
+        ct = PlanTable()
         m = CostModel(table=ct)
         # a: faster p50, fat tail; b: near-tied p50, tight tail
         for i in range(20):
-            ct.observe("t", "sig:a", wall_ms=40.0 if i == 0 else 10.0)
-            ct.observe("t", "sig:b", wall_ms=11.0)
+            ct.observe_cost("t", "sig:a", wall_ms=40.0 if i == 0 else 10.0)
+            ct.observe_cost("t", "sig:b", wall_ms=11.0)
         norm, _, _ = m.choose("t", "d1", [
             Candidate("a", "sig:a", seed_ms=1.0),
             Candidate("b", "sig:b", seed_ms=2.0),
@@ -173,16 +175,16 @@ class TestCostModelChoose:
         assert (burn.name, src) == ("b", "cost-model/slo")
 
     def test_select_route_flips_with_observations(self, fresh):
-        ct = CostTable()
+        ct = PlanTable()
         m = CostModel(table=ct)
         assert m.choose_select_route("t") == "twopass"  # seeded default
         for _ in range(10):
-            ct.observe("t", "sel:twopass", wall_ms=20.0)
-            ct.observe("t", "sel:planned", wall_ms=2.0)
+            ct.observe_cost("t", "sel:twopass", wall_ms=20.0)
+            ct.observe_cost("t", "sel:planned", wall_ms=2.0)
         assert m.choose_select_route("t") == "planned"
 
     def test_join_route_seeds_by_density(self, fresh):
-        m = CostModel(table=CostTable())
+        m = CostModel(table=PlanTable())
         assert m.choose_join_path("t", 0.01) == "block"
         assert m.choose_join_path("t2", 0.9) == "dense"
 
@@ -193,9 +195,12 @@ class TestCostModelChoose:
 
 class TestCalibration:
     def test_record_and_report(self, fresh):
-        m = costmodel.model()
-        m.record_calibration("t", "z3:iv8:rows", 10.0, 8.0)
-        m.record_calibration("t", "z3:iv8:rows", 10.0, 10.0)
+        m = plantable.table()
+        for _ in range(4):  # the prior: a 10 ms p50 worth calibrating
+            m.observe_cost("t", "z3:iv8:rows", wall_ms=10.0)
+        # predicted 10 vs actual 8, then predicted 10 vs actual 10
+        m.record_query("t", "z3:iv8:rows", 8.0, planned=True)
+        m.record_query("t", "z3:iv8:rows", 10.0, planned=True)
         rep = m.calibration_report()
         assert rep["entry_count"] == 1 and rep["samples"] == 2
         e = rep["entries"][0]
@@ -205,14 +210,6 @@ class TestCalibration:
         assert rep["overall_mean_abs_rel_err"] == pytest.approx(
             0.125, abs=1e-3)
 
-    def test_forget_drops_type(self, fresh):
-        m = costmodel.model()
-        m.record_calibration("gone", "z3:rows", 1.0, 1.0)
-        m.record_calibration("kept", "z3:rows", 1.0, 1.0)
-        m.forget("gone")
-        types = {e["type"] for e in m.calibration_report()["entries"]}
-        assert types == {"kept"}
-
     def test_queries_feed_calibration(self, fresh):
         """The audit path records predicted-vs-actual once the plan shape
         has a usable prior."""
@@ -220,7 +217,7 @@ class TestCalibration:
         cql = "BBOX(geom, -60, -30, 60, 30)"
         for _ in range(8):
             ds.query("evt", cql)
-        rep = costmodel.model().calibration_report()
+        rep = plantable.table().calibration_report()
         assert rep["samples"] >= 1
         assert any(e["type"] == "evt" for e in rep["entries"])
 
@@ -247,7 +244,7 @@ class TestCalibration:
         ds.delete_schema("evt")
         assert not any(
             e["type"] == "evt"
-            for e in costmodel.model().calibration_report()["entries"]
+            for e in plantable.table().calibration_report()["entries"]
         )
 
 
@@ -312,12 +309,12 @@ class TestPlannerGolden:
     def test_cost_model_override_flips_choice(self, fresh):
         """Stats prefer z3 for bbox+time; inject measurements proving z2
         serves this type faster — the trained model overrides."""
-        ct = CostTable()
+        ct = PlanTable()
         model = CostModel(table=ct)
         for _ in range(10):
-            ct.observe("evt", "z3:iv64:rows", wall_ms=50.0)
-            ct.observe("evt", "z2:iv64:rows", wall_ms=1.0)
-            ct.observe("evt", "attr:age:rows", wall_ms=2.0)
+            ct.observe_cost("evt", "z3:iv64:rows", wall_ms=50.0)
+            ct.observe_cost("evt", "z2:iv64:rows", wall_ms=1.0)
+            ct.observe_cost("evt", "attr:age:rows", wall_ms=2.0)
         name, dec = self._choose(BOX_TIME, cost_model=model)
         assert name == "z2"
         assert dec["source"] == "cost-model"
@@ -331,11 +328,11 @@ class TestPlannerGolden:
     def test_strategy_probe_cadence(self, fresh):
         """Every PROBE_EVERY-th strategy consult re-measures the losing
         index (bounded: seeds within PROBE_MAX_RATIO)."""
-        ct = CostTable()
+        ct = PlanTable()
         model = CostModel(table=ct)
         for _ in range(10):
-            ct.observe("evt", "z3:iv64:rows", wall_ms=1.0)
-            ct.observe("evt", "z2:iv64:rows", wall_ms=5.0)
+            ct.observe_cost("evt", "z3:iv64:rows", wall_ms=1.0)
+            ct.observe_cost("evt", "z2:iv64:rows", wall_ms=5.0)
         picks = [
             self._choose(BOX_TIME, cost_model=model)[0]
             for _ in range(2 * costmodel.PROBE_EVERY)
@@ -459,8 +456,8 @@ class TestSelectRoute:
         ct = devmon.costs()
         # train the table so the planned route wins outright
         for _ in range(10):
-            ct.observe("evt", "sel:planned", wall_ms=1.0)
-            ct.observe("evt", "sel:twopass", wall_ms=50.0)
+            ct.observe_cost("evt", "sel:planned", wall_ms=1.0)
+            ct.observe_cost("evt", "sel:twopass", wall_ms=50.0)
         assert costmodel.model().choose_select_route("evt") == "planned"
         got = ds.query("evt", cql)
         want = ref.query("evt", cql)
@@ -571,8 +568,8 @@ class TestJoinRoute:
         ds, polys = self._poly_store()
         ct = devmon.costs()
         for _ in range(10):
-            ct.observe("evt", "join:block", wall_ms=50.0)
-            ct.observe("evt", "join:dense", wall_ms=1.0)
+            ct.observe_cost("evt", "join:block", wall_ms=50.0)
+            ct.observe_cost("evt", "join:dense", wall_ms=1.0)
         counts, route = join_counts_auto(ds, "evt", polys)
         assert route == "dense"
 
@@ -633,7 +630,7 @@ class TestReviewPins:
     def test_zero_seed_floor_skips_probe(self, fresh):
         """A 0-row best estimate gives the PROBE_MAX_RATIO bound nothing
         to anchor on: the probe is skipped, never unbounded."""
-        m = CostModel(table=CostTable())
+        m = CostModel(table=PlanTable())
         picks = [
             m.choose("t", "d", [
                 Candidate("tiny", "sig:a", est_rows=0.0),

@@ -13,8 +13,9 @@ import pytest
 
 from geomesa_tpu import obs
 from geomesa_tpu.geometry import Point
-from geomesa_tpu.obs import devmon, jaxmon
-from geomesa_tpu.obs.devmon import CostTable, ResidencyLedger
+from geomesa_tpu.obs import devmon, jaxmon, plantable
+from geomesa_tpu.obs.devmon import ResidencyLedger
+from geomesa_tpu.obs.plantable import PlanTable
 from geomesa_tpu.obs.flight import FlightRecorder
 from geomesa_tpu.obs import flight
 from geomesa_tpu.planning.planner import Query
@@ -29,10 +30,12 @@ CQL = "BBOX(geom, -50, -25, 50, 25) AND dtg AFTER 2017-07-02T00:00:00Z"
 
 @pytest.fixture()
 def fresh():
-    """Isolated ledger + cost table for the test; restored after."""
-    prev = devmon.install(ResidencyLedger(), CostTable())
+    """Isolated ledger + plan table for the test; restored after."""
+    prev = devmon.install(ResidencyLedger())
+    prev_table = plantable.install()
     yield
-    devmon.install(*prev)
+    devmon.install(prev)
+    plantable.install(prev_table)
 
 
 def _fill(ds, n=1500, seed=11):
@@ -144,7 +147,7 @@ class TestLedger:
     def test_over_budget_spill_reported(self, fresh):
         ds0 = _store(1200)
         z3_bytes = ds0.device_residency("evt")["indices"]["z3"]
-        prev = devmon.install(ResidencyLedger(), CostTable())
+        prev = devmon.install(ResidencyLedger())
         try:
             ds = DataStore(
                 backend=TpuBackend(max_device_bytes=int(z3_bytes * 1.5)))
@@ -160,7 +163,7 @@ class TestLedger:
             assert snap["headroom_bytes"] is not None
             assert snap["headroom_bytes"] >= 0
         finally:
-            devmon.install(*prev)
+            devmon.install(prev)
 
     def test_evict_clears_entries_and_spills(self, fresh):
         ds = _store(1500)
@@ -321,7 +324,7 @@ class TestDevprof:
             _ = devmon.current_profile() if devmon.PROFILING else None
         per_check = (time.perf_counter_ns() - t0) / N
         # ... plus the REAL per-query work _audit added: a plan signature,
-        # one cost-table observe, and one ledger index-bytes lookup —
+        # one plan-table write, and one ledger index-bytes lookup —
         # timed against the live singletons so growth in any of them
         # (a slower lock, an O(n) scan) moves this bound, not just the
         # flag check in isolation
@@ -334,8 +337,8 @@ class TestDevprof:
         t0 = time.perf_counter_ns()
         for _ in range(M):
             sig = devmon.plan_signature(_Info())
-            devmon.costs().observe(
-                "evt", sig, wall_ms=1.0, rows=10,
+            devmon.costs().record_query(
+                "evt", sig, 1.0, rows=10, planned=True,
                 bytes_scanned=devmon.ledger().index_bytes("evt", "z3"))
         per_audit = (time.perf_counter_ns() - t0) / M
         overhead = n_dispatch * per_check + per_audit
@@ -396,10 +399,10 @@ class TestH2dDedupe:
 
 class TestCostTable:
     def test_observe_predict_snapshot(self):
-        ct = CostTable()
+        ct = PlanTable()
         assert ct.predict("t", "z3:rows") is None
         for i in range(10):
-            ct.observe("t", "z3:rows", wall_ms=10.0 + i,
+            ct.observe_cost("t", "z3:rows", wall_ms=10.0 + i,
                        device_ms=2.0, rows=100, bytes_scanned=4096)
         p = ct.predict("t", "z3:rows")
         assert p["observations"] == 10
@@ -413,24 +416,15 @@ class TestCostTable:
         assert e["bytes_scanned_p50"] == 4096
 
     def test_device_ms_optional(self):
-        ct = CostTable()
-        ct.observe("t", "sig", wall_ms=5.0)
+        ct = PlanTable()
+        ct.observe_cost("t", "sig", wall_ms=5.0)
         p = ct.predict("t", "sig")
         assert p["device_ms_p50"] is None
 
-    def test_bounded_entries_evict_oldest(self):
-        ct = CostTable(max_entries=4)
-        for i in range(8):
-            ct.observe("t", f"s{i}", wall_ms=1.0)
-        snap = ct.snapshot()
-        assert snap["entry_count"] == 4
-        assert {e["signature"] for e in snap["entries"]} == {
-            "s4", "s5", "s6", "s7"}
-
     def test_non_finite_wall_skipped(self):
-        ct = CostTable()
-        ct.observe("t", "s", wall_ms=float("nan"))
-        ct.observe("t", "s", wall_ms=float("inf"))
+        ct = PlanTable()
+        ct.observe_cost("t", "s", wall_ms=float("nan"))
+        ct.observe_cost("t", "s", wall_ms=float("inf"))
         assert ct.predict("t", "s") is None
 
     def test_plan_signature_shapes(self):

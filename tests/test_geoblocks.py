@@ -22,14 +22,14 @@ SPEC = "name:String,val:Double,cnt:Integer,dtg:Date,*geom:Point"
 
 @pytest.fixture(autouse=True)
 def _fresh_costs():
-    """The cost table (and its routing probe phase) is process-global:
+    """The plan table (and its routing probe phase) is process-global:
     without isolation, earlier tests' consult ticks decide which test
     lands on the every-16th probe-the-loser route — order-fragile."""
-    from geomesa_tpu.obs.devmon import CostTable
+    from geomesa_tpu.obs import plantable
 
-    prev = devmon.install(new_costs=CostTable())
+    prev = plantable.install()
     yield
-    devmon.install(new_costs=prev[1])
+    plantable.install(prev)
 
 
 def mk(backend="tpu", n=3000, seed=21, compact=True):
@@ -384,47 +384,47 @@ class TestQueryCacheUnit:
         assert qc.snapshot()["evictions"] == 1
 
     def test_choose_agg_path_consults_cost_table(self):
-        from geomesa_tpu.obs.devmon import CostTable
+        from geomesa_tpu.obs.plantable import PlanTable
         from geomesa_tpu.planning.planner import choose_agg_path
 
-        ct = CostTable()
+        ct = PlanTable()
         assert choose_agg_path(ct, "t") == "pyramid"  # no data: default
         for _ in range(10):
-            ct.observe("t", "gagg:pyramid", wall_ms=10.0)
-            ct.observe("t", "gagg:scan", wall_ms=1.0)
+            ct.observe_cost("t", "gagg:pyramid", wall_ms=10.0)
+            ct.observe_cost("t", "gagg:scan", wall_ms=1.0)
         assert choose_agg_path(ct, "t") == "scan"
-        ct2 = CostTable()
+        ct2 = PlanTable()
         for _ in range(10):
-            ct2.observe("t", "gagg:pyramid", wall_ms=1.0)
-            ct2.observe("t", "gagg:scan", wall_ms=10.0)
+            ct2.observe_cost("t", "gagg:pyramid", wall_ms=1.0)
+            ct2.observe_cost("t", "gagg:scan", wall_ms=10.0)
         assert choose_agg_path(ct2, "t") == "pyramid"
 
     def test_agg_route_probes_the_loser(self):
-        from geomesa_tpu.obs.devmon import CostTable
+        from geomesa_tpu.obs.plantable import PlanTable
         from geomesa_tpu.planning.planner import (AGG_PROBE_EVERY,
                                                   choose_agg_path)
 
         # scan wins — but the pyramid must still be probed periodically
         # so its profile stays fresh and the verdict can flip back
-        ct = CostTable()
+        ct = PlanTable()
         for _ in range(10):
-            ct.observe("t", "gagg:pyramid", wall_ms=10.0)
-            ct.observe("t", "gagg:scan", wall_ms=1.0)
+            ct.observe_cost("t", "gagg:pyramid", wall_ms=10.0)
+            ct.observe_cost("t", "gagg:scan", wall_ms=1.0)
         routes = [choose_agg_path(ct, "t")
                   for _ in range(2 * AGG_PROBE_EVERY)]
         assert routes.count("pyramid") == 2
         # symmetric: a pyramid-default workload (scan has NO observations
         # and could otherwise never qualify) still measures the scan
-        ct2 = CostTable()
+        ct2 = PlanTable()
         routes2 = [choose_agg_path(ct2, "t")
                    for _ in range(AGG_PROBE_EVERY)]
         assert routes2.count("scan") == 1
         # the schedule rides the consult counter, not observation counts:
         # consults that never observe still advance toward the next probe
-        ct3 = CostTable()
+        ct3 = PlanTable()
         for _ in range(10):
-            ct3.observe("t", "gagg:pyramid", wall_ms=10.0)
-            ct3.observe("t", "gagg:scan", wall_ms=1.0)
+            ct3.observe_cost("t", "gagg:pyramid", wall_ms=10.0)
+            ct3.observe_cost("t", "gagg:scan", wall_ms=1.0)
         seen = set()
         for _ in range(2 * AGG_PROBE_EVERY):
             seen.add(choose_agg_path(ct3, "t"))

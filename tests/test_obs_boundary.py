@@ -18,7 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from geomesa_tpu import obs
 from geomesa_tpu.geometry import Point
-from geomesa_tpu.obs import ledger
+from geomesa_tpu.obs import ledger, plantable
 from geomesa_tpu.parallel.mesh import DATA_AXIS, QUERY_AXIS
 from geomesa_tpu.schema.sft import parse_spec
 from geomesa_tpu.store.datastore import DataStore
@@ -142,13 +142,13 @@ def test_aggregate_many_span_tree(ds, monkeypatch):
 def test_count_many_charges_the_ledger(ds):
     # compile the batch's shape outside the measured table
     ds.count_many("pts", [_cql(40 + i) for i in range(4)])
-    prev = ledger.install(ledger.LedgerTable())
+    prev = plantable.install()
     try:
         ds.count_many("pts", [_cql(44 + i) for i in range(4)])
         rows = [e for e in ledger.table().export()["entries"]
                 if e["type"] == "pts"]
     finally:
-        ledger.install(prev)
+        plantable.install(prev)
     # the batch's shared ledger is charged once per member query, as
     # select_many's is: each carries the one dispatch and the one sync
     assert sum(e["queries"] for e in rows) == 4

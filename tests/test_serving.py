@@ -379,8 +379,7 @@ class TestCoalesce:
         adaptive planner's cost table keeps training on web traffic."""
         from geomesa_tpu.obs import devmon
 
-        prev = devmon.install(devmon.ResidencyLedger(),
-                              devmon.CostTable())
+        prev = devmon.install(devmon.ResidencyLedger())
         try:
             ds = make_store()
             app = GeoMesaApp(ds, coalesce_ms=20.0)
@@ -389,7 +388,7 @@ class TestCoalesce:
             assert s == 200
             assert devmon.costs().snapshot()["entry_count"] >= 1
         finally:
-            devmon.install(*prev)
+            devmon.install(prev)
 
     def test_count_and_aggregate_ops_parity(self):
         ds = make_store()

@@ -167,7 +167,7 @@ class TestPackageFlowGate:
                 "buffer-pool", "track-state-cache"} <= surfaces
         sinks = {d["fn"] for d in inv["feedback_sinks"]}
         assert len(sinks) >= 4
-        assert {"CostTable.observe", "UsageMeter.observe",
+        assert {"PlanTable.record_query", "UsageMeter.observe",
                 "SloEngine.observe"} <= sinks
         roots = {r["name"] for r in inv["shadow_planes"]}
         assert {"ContinuousAuditor", "InvariantSweeper"} <= roots

@@ -461,18 +461,19 @@ class TestReconcile:
 
 
 class TestLedgerExportSurfaces:
-    """The measured side: LedgerTable.export(), the web route, and the
+    """The measured side: the plan table's export(), the web route, and the
     CLI puller all speak the one schema the analyzer validates."""
 
     def _charged_table(self):
-        from geomesa_tpu.obs.ledger import LedgerTable, QueryLedger
+        from geomesa_tpu.obs.ledger import QueryLedger
+        from geomesa_tpu.obs.plantable import PlanTable
 
-        t = LedgerTable()
+        t = PlanTable()
         ql = QueryLedger()
         ql.note_dispatch(0.0, 0.001)
         ql.note_dispatch(0.002, 0.003)
         ql.note_sync(0.003, 0.004)
-        t.charge("pts", "z2:iv16:rows", ql, 5.0)
+        t.record_query("pts", "z2:iv16:rows", 5.0, ledger=ql)
         return t
 
     def test_export_round_trips_through_loader(self, tmp_path):
@@ -491,12 +492,12 @@ class TestLedgerExportSurfaces:
     def test_web_route_serves_the_export_schema(self):
         import io
 
-        from geomesa_tpu.obs import ledger as ledger_mod
+        from geomesa_tpu.obs import plantable
         from geomesa_tpu.store.datastore import DataStore
         from geomesa_tpu.web.app import GeoMesaApp
 
         app = GeoMesaApp(DataStore(backend="tpu"))
-        prev = ledger_mod.install(self._charged_table())
+        prev = plantable.install(self._charged_table())
         try:
             def call(query):
                 environ = {
@@ -524,7 +525,7 @@ class TestLedgerExportSurfaces:
             status, doc = call("format=csv")
             assert status == 400
         finally:
-            ledger_mod.install(prev)
+            plantable.install(prev)
 
     def test_cli_export_writes_loader_valid_file(self, tmp_path):
         import argparse
@@ -532,7 +533,7 @@ class TestLedgerExportSurfaces:
         from wsgiref.simple_server import WSGIRequestHandler, make_server
 
         from geomesa_tpu.cli.__main__ import cmd_obs_ledger_export
-        from geomesa_tpu.obs import ledger as ledger_mod
+        from geomesa_tpu.obs import plantable
         from geomesa_tpu.store.datastore import DataStore
         from geomesa_tpu.web.app import GeoMesaApp
 
@@ -543,14 +544,14 @@ class TestLedgerExportSurfaces:
         app = GeoMesaApp(DataStore(backend="tpu"))
         httpd = make_server("127.0.0.1", 0, app, handler_class=_Quiet)
         threading.Thread(target=httpd.serve_forever, daemon=True).start()
-        prev = ledger_mod.install(self._charged_table())
+        prev = plantable.install(self._charged_table())
         out_path = tmp_path / "ledger.json"
         try:
             cmd_obs_ledger_export(argparse.Namespace(
                 url=f"http://127.0.0.1:{httpd.server_address[1]}",
                 timeout=10.0, output=str(out_path), limit=32, json=False))
         finally:
-            ledger_mod.install(prev)
+            plantable.install(prev)
             httpd.shutdown()
         (e,) = load_ledger_export(str(out_path))
         assert e["signature"] == "z2:iv16:rows"
@@ -568,7 +569,7 @@ class TestReconcileLiveExport:
 
         from geomesa_tpu.geometry.types import Point
         from geomesa_tpu.obs import ledger as ledger_mod
-        from geomesa_tpu.obs.ledger import LedgerTable
+        from geomesa_tpu.obs import plantable
         from geomesa_tpu.store import backends
         from geomesa_tpu.store.datastore import DataStore
 
@@ -590,13 +591,13 @@ class TestReconcileLiveExport:
         cql = "BBOX(geom,-50,-40,50,40)"
         try:
             ds.query("pts", cql)  # compile the staged steps
-            prev = ledger_mod.install(LedgerTable())
+            prev = plantable.install()
             try:
                 for _ in range(3):
                     ds.query("pts", cql)
                 doc = ledger_mod.table().export()
             finally:
-                ledger_mod.install(prev)
+                plantable.install(prev)
         finally:
             backends._ONE_PASS_MAX_SLOTS = prev_slots
         path = tmp_path_factory.mktemp("ledger") / "ledger.json"

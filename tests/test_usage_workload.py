@@ -643,22 +643,21 @@ class TestFederatedTenantPropagation:
 
 class TestCostPersistence:
     def test_snapshot_load_round_trip(self, tmp_path):
-        from geomesa_tpu.obs import devmon
-        from geomesa_tpu.planning import costmodel
+        from geomesa_tpu.obs import devmon, plantable
 
         path = str(tmp_path / "costs.json")
         ct = devmon.costs()
         for i in range(20):
-            ct.observe("pts", "z3:iv4:rows", wall_ms=2.0 + (i % 3),
-                       rows=10)
+            ct.observe_cost("pts", "z3:iv4:rows", wall_ms=2.0 + (i % 3),
+                            rows=10)
         ct.tick("pts", "select_route")
-        costmodel.model().record_calibration("pts", "z3:iv4:rows", 2.5, 3.0)
+        # one planned query: predicted-vs-actual lands in the calibration
+        ct.record_query("pts", "z3:iv4:rows", 3.0, rows=10, planned=True)
         before = ct.predict("pts", "z3:iv4:rows")
         assert devmon.save_cost_snapshot(path) == path
 
-        # "restart": fresh table + model
-        devmon.install(new_costs=devmon.CostTable())
-        costmodel.install(costmodel.CostModel())
+        # "restart": a fresh table
+        plantable.install()
         assert devmon.costs().predict("pts", "z3:iv4:rows") is None
         assert devmon.load_cost_snapshot(path)
         after = devmon.costs().predict("pts", "z3:iv4:rows")
@@ -668,7 +667,7 @@ class TestCostPersistence:
         assert after["observations"] == before["observations"]
         # probe cadence survives: the tick counter continues, not restarts
         assert devmon.costs().tick("pts", "select_route") == 2
-        cal = costmodel.model().calibration_report()
+        cal = devmon.costs().calibration_report()
         assert cal["entry_count"] == 1
         assert cal["entries"][0]["last_actual_ms"] == pytest.approx(3.0)
 
@@ -681,11 +680,11 @@ class TestCostPersistence:
         path = str(tmp_path / "costs.json")
         ct = devmon.costs()
         for _ in range(5):
-            ct.observe("pts", "sig", wall_ms=100.0)
+            ct.observe_cost("pts", "sig", wall_ms=100.0)
         devmon.save_cost_snapshot(path)
         # the live table learns on, past the snapshot, at a new level
         for _ in range(20):
-            ct.observe("pts", "sig", wall_ms=1.0)
+            ct.observe_cost("pts", "sig", wall_ms=1.0)
         before = ct.predict("pts", "sig")
         assert devmon.load_cost_snapshot(path)
         after = ct.predict("pts", "sig")
@@ -713,7 +712,7 @@ class TestCostPersistence:
 
     def test_catalog_save_load_round_trips_costs(self, tmp_path,
                                                  monkeypatch):
-        from geomesa_tpu.obs import devmon
+        from geomesa_tpu.obs import devmon, plantable
         from geomesa_tpu.store import persistence
 
         monkeypatch.setenv("GEOMESA_TPU_WORKLOAD_DIR",
@@ -723,7 +722,7 @@ class TestCostPersistence:
         sig_rows = devmon.costs().snapshot()["entries"]
         assert sig_rows
         persistence.save(ds, str(tmp_path / "cat"))
-        devmon.install(new_costs=devmon.CostTable())
+        plantable.install()
         assert not devmon.costs().snapshot()["entries"]
         ds2 = persistence.load(str(tmp_path / "cat"))
         loaded = devmon.costs().snapshot()["entries"]

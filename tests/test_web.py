@@ -181,7 +181,7 @@ class TestQueryAndStats:
         in the Prometheus exposition."""
         from geomesa_tpu.obs import devmon
 
-        prev = devmon.install(devmon.ResidencyLedger(), devmon.CostTable())
+        prev = devmon.install(devmon.ResidencyLedger())
         try:
             _ingest(app, n=1500)  # enough rows to go device-resident
             status, out = jcall(app, "GET", "/api/metrics")
@@ -200,7 +200,7 @@ class TestQueryAndStats:
                     '{type="pts",index="z3",group="spatial"}') in text
             assert "geomesa_device_resident_bytes_total" in text
         finally:
-            devmon.install(*prev)
+            devmon.install(prev)
 
     def test_metrics_cache_gauges(self, app):
         """ISSUE 7 satellite: geomesa_cache_{hits,misses,evictions},
@@ -231,7 +231,7 @@ class TestQueryAndStats:
     def test_obs_costs_endpoint(self, app):
         from geomesa_tpu.obs import devmon
 
-        prev = devmon.install(devmon.ResidencyLedger(), devmon.CostTable())
+        prev = devmon.install(devmon.ResidencyLedger())
         try:
             _ingest(app, n=1500)
             jcall(app, "GET", "/api/schemas/pts/query",
@@ -247,7 +247,7 @@ class TestQueryAndStats:
             cal = out["calibration"]
             assert {"entries", "entry_count", "samples"} <= set(cal)
         finally:
-            devmon.install(*prev)
+            devmon.install(prev)
 
     def test_count_many(self, app):
         _ingest(app)
