@@ -77,6 +77,10 @@ class FeatureIndex:
         self.sft = sft
         self.perm: np.ndarray | None = None  # sorted position -> original row
         self.n = 0
+        # the rows themselves in key order, for indexes that keep them
+        # (z3): ``features`` row ``rank[r]`` is original row ``r``
+        self.features: FeatureTable | None = None
+        self.rank: np.ndarray | None = None  # original row -> sorted position
 
     # -- capability tests (StrategyDecider inputs) ---------------------------
     @classmethod

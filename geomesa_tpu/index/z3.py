@@ -133,7 +133,18 @@ class Z3Index(FeatureIndex):
         self.zs = z[perm]
         self.n = len(table)
         self.bin_values, self.bin_starts = np.unique(self.bins, return_index=True)
+        self._keep_rows(table, perm)
         return perm
+
+    def _keep_rows(self, table: FeatureTable, perm: np.ndarray) -> None:
+        """Keep the rows in key order, as GeoMesa stores each feature under
+        its index key (SURVEY.md §2.3): a select's matches then lie in
+        contiguous runs of ``features``, where they are scattered across
+        the arrival-ordered table. String objects are shared, not copied."""
+        self.features = table.take(perm)
+        rank = np.empty(len(perm), dtype=np.int64)
+        rank[perm] = np.arange(len(perm), dtype=np.int64)
+        self.rank = rank
 
     def merge_build(self, table: FeatureTable, prev: "Z3Index", n_prev: int) -> np.ndarray:
         """LSM-style incremental build: ``table`` = [prev's rows | delta].
@@ -174,6 +185,7 @@ class Z3Index(FeatureIndex):
         )
         self.n = n
         self.bin_values, self.bin_starts = np.unique(self.bins, return_index=True)
+        self._keep_rows(table, perm)
         return perm
 
     # -- planning ------------------------------------------------------------

@@ -81,6 +81,20 @@ class ExecutionBackend:
         raise NotImplementedError
 
 
+def refine_rows(index: FeatureIndex, residual, table: FeatureTable,
+                positions: np.ndarray) -> np.ndarray:
+    """Original rows at the key-order ``positions`` that pass ``residual``.
+
+    Where the index keeps its rows in key order (``index.features``), the
+    residual reads them there, near-sequentially, and only the survivors
+    map to original rows; the mask is the same, element for element."""
+    if index.features is None:
+        rows = index.perm[positions]
+        return rows[ast.residual_mask(residual, table, rows)]
+    keep = ast.residual_mask(residual, index.features, positions)
+    return index.perm[positions[keep]]
+
+
 class OracleBackend(ExecutionBackend):
     """Brute force: evaluate the full filter over every row (referee)."""
 
@@ -568,8 +582,7 @@ class TpuBackend(ExecutionBackend):
                 self.pool.note_miss(type_name, index.name)
             with obs.span("refine", mode="host", index=index.name):
                 positions, total = gather_indices(intervals)
-                rows = index.perm[positions[:total]]
-                return rows[ast.residual_mask(residual, table, rows)]
+                return refine_rows(index, residual, table, positions[:total])
 
         # adaptive dispatch route (planning/costmodel.py): "twopass" is the
         # per-query candidate-slot count+gather; "planned" runs the batched
@@ -612,11 +625,10 @@ class TpuBackend(ExecutionBackend):
                 wall_ms=(_time.perf_counter() - t0) * 1000.0,
                 rows=len(positions),
             )
-        rows = index.perm[positions]
         if isinstance(residual, ast.Include):
-            return rows
-        with obs.span("refine", candidates=len(rows)):
-            return rows[ast.residual_mask(residual, table, rows)]
+            return index.perm[positions]
+        with obs.span("refine", candidates=len(positions)):
+            return refine_rows(index, residual, table, positions)
 
     @dispatch_budget(2)
     def select_many_positions(

@@ -29,7 +29,12 @@ from geomesa_tpu.index.api import FeatureIndex
 from geomesa_tpu.planning.planner import Query, QueryPlanner, build_indices
 from geomesa_tpu.schema.columnar import FeatureTable
 from geomesa_tpu.schema.sft import FeatureType, parse_spec
-from geomesa_tpu.store.backends import ExecutionBackend, OracleBackend, TpuBackend
+from geomesa_tpu.store.backends import (
+    ExecutionBackend,
+    OracleBackend,
+    TpuBackend,
+    refine_rows,
+)
 
 if TYPE_CHECKING:
     from geomesa_tpu.store.bufferpool import BufferPool
@@ -1141,6 +1146,33 @@ class DataStore:
 
         return sorter
 
+    def _take_combined(self, sft, main, indices, main_n: int, delta_table,
+                       rows: np.ndarray) -> FeatureTable:
+        """Materialize rows addressed in the virtual (main ++ delta) row space.
+
+        Main-tier rows come from the z3 index's key-ordered copy where it
+        keeps one: a select's matches lie in contiguous runs there, so the
+        gather reads near-sequential memory. The result is the same rows in
+        the same order, column for column. Counts the main-tier rows
+        materialized (``store.reduce.rows``) and those the copy served
+        (``store.reduce.rows_keyed``)."""
+        parts = []
+        main_sel = rows[rows < main_n]
+        delta_sel = rows[rows >= main_n] - main_n
+        if len(main_sel):
+            keyed = indices.get("z3")
+            self.metrics.counter("store.reduce.rows").inc(len(main_sel))
+            if keyed is not None and keyed.features is not None:
+                self.metrics.counter("store.reduce.rows_keyed").inc(len(main_sel))
+                parts.append(keyed.features.take(keyed.rank[main_sel]))
+            else:
+                parts.append(main.take(main_sel))
+        if len(delta_sel):
+            parts.append(delta_table.take(delta_sel))
+        if not parts:
+            return FeatureTable.from_records(sft, [])
+        return parts[0] if len(parts) == 1 else FeatureTable.concat(parts)
+
     def _rebuild(self, st: _TypeState, table: FeatureTable, prev_indices=None,
                  n_prev: int = 0, consumed_tables: int = 0, new_sft=None) -> None:
         """Swap in a new main tier built from ``table`` (delta folded in).
@@ -1466,7 +1498,8 @@ class DataStore:
                     rows = np.concatenate([rows, drows + main_n])
 
             with obs.span("reduce", rows=len(rows)):
-                table = _take_combined(st.sft, main, main_n, delta_table, rows)
+                table = self._take_combined(st.sft, main, indices, main_n,
+                                            delta_table, rows)
 
                 # shared post-scan pipeline: visibility, sampling, aggregation
                 # hints, sort/limit/projection/CRS (LocalQueryRunner shape)
@@ -1957,19 +1990,20 @@ class DataStore:
                 # dispatch spans above cover the device half
                 with obs.span("query", batch_index=i, batched=True):
                     with obs.span("refine", candidates=len(positions)):
-                        rows = index.perm[positions]
                         # exact residual: same contract as backend.select
                         # (int superset culled on device, f64 filter
                         # settles the rest)
-                        if len(rows) and not isinstance(f, ast.Include):
-                            rows = rows[ast.residual_mask(f, main, rows)]
+                        if len(positions) and not isinstance(f, ast.Include):
+                            rows = refine_rows(index, f, main, positions)
+                        else:
+                            rows = index.perm[positions]
                         rows = np.sort(rows)
                         if delta_table is not None:
                             drows = np.nonzero(f.mask(delta_table))[0]
                             rows = np.concatenate([rows, drows + main_n])
                     with obs.span("reduce", rows=len(rows)):
-                        table = _take_combined(st.sft, main, main_n,
-                                               delta_table, rows)
+                        table = self._take_combined(
+                            st.sft, main, indices, main_n, delta_table, rows)
                         tbl, rws, density, stats_out, bin_data = reduce_result(
                             st.sft, table, rows, q)
                     tail_ms = (_time.perf_counter() - tq0) * 1000.0
@@ -3608,19 +3642,3 @@ def _pyramid_evictor(st: "_TypeState", pkey, pyr):
                 del st.pyramids[pkey]
 
     return _evict
-
-
-def _take_combined(sft, main, main_n: int, delta_table, rows: np.ndarray) -> FeatureTable:
-    """Materialize rows addressed in the virtual (main ++ delta) row space."""
-    parts = []
-    main_sel = rows[rows < main_n]
-    delta_sel = rows[rows >= main_n] - main_n
-    if len(main_sel):
-        parts.append(main.take(main_sel))
-    if len(delta_sel):
-        parts.append(delta_table.take(delta_sel))
-    if not parts:
-        return FeatureTable.from_records(sft, [])
-    return parts[0] if len(parts) == 1 else FeatureTable.concat(parts)
-
-
