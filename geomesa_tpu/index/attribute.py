@@ -70,16 +70,20 @@ class AttributeIndex(FeatureIndex):
         col = table.columns[self.attribute]
         valid = col.is_valid()
         vals = col.values
+        # a string column sorts by its dictionary codes, which rank the
+        # set values exactly: the same stable order, with no string compare
+        ranked = col.ranked()
+        key = vals if ranked is None else ranked[1]
         # sortable surrogate: None -> pushed to end via the valid flag
         if self.tiered:
             tcol = table.geom_column()
             bins, offs = self.binned.to_bin_and_offset(table.dtg_millis())
             z = self.sfc.index(tcol.x, tcol.y, offs)
-            order = stable_lexsort([z, bins, _sort_surrogate(vals, valid), ~valid])
+            order = stable_lexsort([z, bins, _sort_surrogate(key, valid), ~valid])
             self.bins = bins[order]
             self.zs = z[order]
         else:
-            order = stable_lexsort([_sort_surrogate(vals, valid), ~valid])
+            order = stable_lexsort([_sort_surrogate(key, valid), ~valid])
         self.perm = order
         self.values = vals[order]
         self.n = len(table)

@@ -58,29 +58,77 @@ class Column:
         ``ArrowDictionary`` role. Predicates evaluate against the (small)
         vocab once and compare int codes per row instead of strings
         (``ArrowFilterOptimizer.scala:1`` pushdown); None for non-strings.
+        Invalid and non-str slots map to "".
         """
         if self.type not in (AttributeType.STRING, AttributeType.UUID):
             return None
+        return self._factorized()[:2]
+
+    def ranked(self):
+        """``(distinct, codes)`` when the dictionary's codes rank the set
+        values exactly: ``distinct`` holds the column's own sorted distinct
+        strings (one object per vocab entry) and ``codes`` are
+        :meth:`dictionary`'s, so counting or sorting codes at the set rows
+        counts or sorts the strings themselves. None for non-string or
+        non-object columns, a set value that is not a ``str``, or distinct
+        strings that the unicode vocab merges (trailing NULs)."""
+        if (self.type not in (AttributeType.STRING, AttributeType.UUID)
+                or self.values.dtype != object):
+            return None
+        _, codes, distinct = self._factorized()
+        return None if distinct is None else (distinct, codes)
+
+    def _factorized(self):
         cached = self.__dict__.get("_dict")
-        if cached is not None:
-            return cached
-        if self.valid is None and all(
-            type(v) is str for v in self.values
-        ):
-            # genuinely all-str: vectorized C-level cast. The type sweep is
-            # ~10x cheaper than the guarded listcomp+astype below, and it
-            # guards semantics: a stray non-str value must keep mapping to
-            # "" (str(v) here would change filter/grouping results)
-            flat = np.asarray(self.values, dtype=str)
-        else:
-            flat = np.array(
-                [v if isinstance(v, str) else "" for v in self.values],
-                dtype=object,
-            ).astype(str)
-        vocab, codes = np.unique(flat, return_inverse=True)
-        out = (vocab, codes.astype(np.int32))
-        self.__dict__["_dict"] = out
-        return out
+        if cached is None:
+            cached = self.__dict__["_dict"] = _factorize_strings(
+                self.values, self.valid)
+        return cached
+
+
+def _factorize_strings(values, valid):
+    """(vocab, codes, distinct) of a string column, built by hashing:
+    factorize the values, sort only the distinct ones, remap the codes by
+    rank. ``vocab`` and ``codes`` equal ``np.unique`` over the column's
+    unicode cast (non-str values as ""); ``distinct`` is the sorted
+    distinct objects when they align one to one with ``vocab`` and every
+    set value is a str, else None."""
+    import pandas as pd
+
+    set_all_str = all_str = pd.api.types.infer_dtype(
+        values, skipna=False) == "string"
+    if all_str:
+        obj = np.asarray(values, dtype=object)
+    else:
+        # a stray non-str value must keep mapping to "" (str(v) here
+        # would change filter and grouping results)
+        obj = np.array([v if isinstance(v, str) else "" for v in values],
+                       dtype=object)
+        if valid is not None:
+            set_all_str = pd.api.types.infer_dtype(
+                values[valid], skipna=False) in ("string", "empty")
+    codes, uniq = pd.factorize(obj)
+    if not (uniq[codes] == obj).all():
+        # pandas' string hash table ends a string at its first NUL: fall
+        # back to Python's own hashing and equality
+        seen: dict = {}
+        codes = np.fromiter((seen.setdefault(v, len(seen)) for v in obj),
+                            np.int64, count=len(obj))
+        uniq = np.empty(len(seen), dtype=object)
+        uniq[:] = list(seen)
+    uv = uniq.astype(str)
+    # stable: timsort takes ids that arrive in order in one pass
+    order = np.argsort(uv, kind="stable")
+    ordered = uv[order]
+    first = np.ones(len(ordered), dtype=bool)
+    np.not_equal(ordered[1:], ordered[:-1], out=first[1:])
+    rank = np.empty(len(ordered), dtype=np.int32)
+    rank[order] = np.cumsum(first) - 1
+    vocab = ordered[first]
+    distinct = None
+    if set_all_str and len(vocab) == len(uniq):
+        distinct = uniq[order]
+    return vocab, rank[codes], distinct
 
 
 @dataclass

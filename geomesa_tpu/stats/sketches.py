@@ -122,9 +122,10 @@ def _hash_basis(values) -> np.ndarray:
 
     Numeric/bool/datetime arrays use their 64-bit patterns directly
     (vectorized — the splitmix-style mixers downstream do the avalanche
-    work); object/string payloads fall back to the per-value Python hash.
-    Batch observe and single-value count both route through here, so the
-    basis stays internally consistent."""
+    work); object/string payloads take the per-value Python hash, one
+    ``hash`` call a value, as the bits of its int64. Batch observe and
+    single-value count both route through here, so the basis stays
+    internally consistent."""
     a = np.asarray(values)
     if a.dtype.kind in "iu":
         return a.astype(np.int64, copy=False).view(np.uint64)
@@ -135,10 +136,8 @@ def _hash_basis(values) -> np.ndarray:
         return a.astype(np.uint64)
     if a.dtype.kind == "M":
         return a.astype("datetime64[ms]").astype(np.int64).view(np.uint64)
-    return np.array(
-        [np.uint64(hash(v) & 0xFFFFFFFFFFFFFFFF) for v in values],
-        dtype=np.uint64,
-    )
+    return np.fromiter(map(hash, values), np.int64,
+                       count=len(values)).view(np.uint64)
 
 
 @dataclass
@@ -159,9 +158,9 @@ class Frequency(Stat):
                 0x9E3779B97F4A7C15
             )
 
-    def _hashes(self, values) -> np.ndarray:
+    def _hashes(self, values, basis=None) -> np.ndarray:
         """(depth, n) bucket indices via splitmix-style mixing."""
-        hv = _hash_basis(values)
+        hv = _hash_basis(values) if basis is None else basis
         out = np.empty((self.depth, len(hv)), dtype=np.int64)
         for d in range(self.depth):
             x = hv * self._seeds[d]
@@ -178,13 +177,14 @@ class Frequency(Stat):
         for d in range(self.depth):
             np.add.at(self.table[d], h[d], 1)
 
-    def observe_weighted(self, values, counts):
+    def observe_weighted(self, values, counts, basis=None):
         """Observe pre-aggregated (unique value, count) pairs — the bulk
-        rebuild path folds each column through np.unique once and feeds
-        the weights here, replacing n per-value updates with u."""
+        rebuild path folds each column to its distinct values once and
+        feeds the weights here, replacing n per-value updates with u.
+        ``basis`` is ``_hash_basis(values)`` where the caller has it."""
         if len(values) == 0:
             return
-        h = self._hashes(values)
+        h = self._hashes(values, basis)
         w = np.asarray(counts, dtype=np.int64)
         for d in range(self.depth):
             np.add.at(self.table[d], h[d], w)
@@ -209,10 +209,11 @@ class Cardinality(Stat):
         if self.registers is None:
             self.registers = np.zeros(1 << self.p, dtype=np.uint8)
 
-    def observe(self, values):
+    def observe(self, values, basis=None):
+        """``basis`` is ``_hash_basis(values)`` where the caller has it."""
         if len(values) == 0:
             return
-        hv = _hash_basis(values)
+        hv = _hash_basis(values) if basis is None else basis
         x = hv * np.uint64(0x9E3779B97F4A7C15)
         x ^= x >> np.uint64(29)
         x *= np.uint64(0xBF58476D1CE4E5B9)

@@ -1192,37 +1192,43 @@ class DataStore:
         # increment is race-free): the backend load's donation fingerprint
         # and, at swap, the new data-epoch component
         next_epoch = st.epoch + 1
-        sorter = self._device_sorter(len(table))
-        for name, index in indices.items():
-            prev = (prev_indices or {}).get(name)
-            if prev is not None and n_prev > 0 and hasattr(index, "merge_build"):
-                index.merge_build(table, prev, n_prev)
-            elif sorter is not None:
+        # spans of the build's phases: labels that no metric reads
+        with obs.span("rebuild", rows=len(table)):
+            sorter = self._device_sorter(len(table))
+            for name, index in indices.items():
+                with obs.span("index", index=name):
+                    prev = (prev_indices or {}).get(name)
+                    if (prev is not None and n_prev > 0
+                            and hasattr(index, "merge_build")):
+                        index.merge_build(table, prev, n_prev)
+                    elif sorter is not None:
+                        try:
+                            index.build(table, sorter=sorter)
+                            self._note_device_ok()  # half-open circuit closes
+                        except Exception as e:  # noqa: BLE001 — degrade, don't die
+                            if not self._is_device_error(e):
+                                raise
+                            self._trip_device_circuit(e)
+                            self.metrics.counter("store.device.sort_failures").inc()
+                            sorter = None  # host sorts for the remaining indexes too
+                            index.build(table)
+                    else:
+                        index.build(table)
+            with obs.span("load"):
                 try:
-                    index.build(table, sorter=sorter)
-                    self._note_device_ok()  # half-open circuit closes
-                except Exception as e:  # noqa: BLE001 — degrade, don't die
+                    backend_state = self.backend.load(
+                        sft, table, indices, fingerprint=next_epoch)
+                except Exception as e:  # noqa: BLE001 — write must not die with the device
                     if not self._is_device_error(e):
                         raise
                     self._trip_device_circuit(e)
-                    self.metrics.counter("store.device.sort_failures").inc()
-                    sorter = None  # host sorts for the remaining indexes too
-                    index.build(table)
-            else:
-                index.build(table)
-        try:
-            backend_state = self.backend.load(
-                sft, table, indices, fingerprint=next_epoch)
-        except Exception as e:  # noqa: BLE001 — write must not die with the device
-            if not self._is_device_error(e):
-                raise
-            self._trip_device_circuit(e)
-            self.metrics.counter("store.device.load_failures").inc()
-            backend_state = None  # host paths serve until recover()
-        from geomesa_tpu.stats.store_stats import StoreStats
+                    self.metrics.counter("store.device.load_failures").inc()
+                    backend_state = None  # host paths serve until recover()
+            from geomesa_tpu.stats.store_stats import StoreStats
 
-        stats = StoreStats(sft)
-        stats.rebuild(table, indices.get("z3"))
+            with obs.span("stats"):
+                stats = StoreStats(sft)
+                stats.rebuild(table, indices.get("z3"))
         with st.lock:
             if new_sft is not None:
                 st.sft = new_sft
